@@ -29,20 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.api import Session, SessionSpec, TopologySpec
 from repro.core import fabsim
 from repro.core.dataplane import ref_all_to_allv
-from repro.core.jax_compat import shard_map
-
-
-def skewed_counts(n, max_chunks, hotspot, rng):
-    """Per (src, dst) chunk counts with a hot destination (Fig. 7)."""
-    counts = np.zeros((n, n), dtype=np.int32)
-    for s in range(n):
-        hd = 0 if s != 0 else 1
-        budget = max_chunks
-        counts[s, hd] = int(round(budget * hotspot))
-        others = [d for d in range(n) if d not in (s, hd)]
-        for d in others:
-            counts[s, d] = int(budget * (1 - hotspot) / len(others))
-    return counts
+from repro.launch.selftest import hot_spot_counts
 
 
 def main():
@@ -53,7 +40,7 @@ def main():
     spec = SessionSpec(topology=TopologySpec(n_devices=n, group_size=4))
     with Session(spec) as sess:
         for hotspot in [0.3, 0.7, 0.9]:
-            counts = skewed_counts(n, C, hotspot, rng)
+            counts = hot_spot_counts(n, C, hotspot)
             x_all = rng.normal(size=(n, n, C, E)).astype(np.float32)
             for s in range(n):
                 for d in range(n):
@@ -64,7 +51,7 @@ def main():
             for mode in ["direct", "stripe", "nimble"]:
                 comm = sess.all_to_all("x", max_chunks=C, chunk_bytes=E * 4,
                                        mode=mode)
-                fn = shard_map(lambda x, c: comm(x, c), mesh=mesh,
+                fn = jax.shard_map(lambda x, c: comm(x, c), mesh=mesh,
                                in_specs=(P("x"), P("x")),
                                out_specs=(P("x"), P("x")))
                 y, r = jax.jit(fn)(jnp.asarray(x_all.reshape(n * n, C, E)),

@@ -32,8 +32,8 @@ import numpy as np
 
 from repro.api import Session, SessionSpec, TopologySpec
 from repro.configs.base import get_config
-from repro.core.jax_compat import set_mesh
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.mesh import make_test_mesh
 from repro.models.registry import build_model
 from repro.optim import adamw
 from repro.sharding.context import ParallelContext
@@ -72,7 +72,7 @@ def main(argv=None):
         steps = args.steps or 200
         seq = args.seq or 128
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh(model=4)
     # one declarative session describes the EP fabric (4 chips = 2 "nodes"
     # x 2) and hands the model zoo ready-wired NIMBLE dispatchers
     session = Session(SessionSpec(
@@ -93,7 +93,7 @@ def main(argv=None):
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=seq,
                                   global_batch=args.batch, seed=args.seed))
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(params, build_param_shardings(params, ctx))
         jf = jax.jit(step_fn, donate_argnums=(0, 1))
         losses, t0 = [], time.time()

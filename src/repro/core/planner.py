@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .cost import CostModel
-from .jax_compat import pvary
 from .schedule import PlannerTables
 
 _BIG = 1e30
@@ -162,8 +161,8 @@ def plan_flows(
     if vary_axis is not None:
         # inside shard_map the demand is axis-varying; the loop carries must
         # match or lax.fori_loop rejects the body signature.
-        flows = pvary(flows, vary_axis)
-        loads0 = pvary(loads0, vary_axis)
+        flows = jax.lax.pcast(flows, vary_axis, to="varying")
+        loads0 = jax.lax.pcast(loads0, vary_axis, to="varying")
     flows, res, loads = jax.lax.fori_loop(
         0, cfg.n_iters, body, (flows, D, loads0)
     )

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 _NEG_INF = -1e30
 
 
@@ -79,7 +81,7 @@ def flash_attention(
     q_offset: int = 0,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     b, h, sq, dh = q.shape
     hkv, sk = k.shape[1], k.shape[2]
@@ -111,5 +113,6 @@ def flash_attention(
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        name="flash_attention",
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .flash import flash_attention as _flash
 from .ref import mha_ref
@@ -101,11 +102,41 @@ def _bwd(causal, window, q_offset, res, g):
 _attention_tpu.defvjp(_fwd, _bwd)
 
 
+def _on_mesh(q, k, v, causal, window, q_offset):
+    """The Pallas kernel under the ambient mesh (``jax.set_mesh``).
+
+    XLA cannot partition a Mosaic kernel, so on a mesh of several devices the
+    kernel runs per shard inside ``shard_map``: attention is independent per
+    sequence and per group of heads, so the batch is split over the mesh axes
+    it divides, the heads (q and kv in the same contiguous blocks, which keeps
+    the GQA grouping) over those left, and any other axis holds a replica.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return _attention_tpu(q, k, v, causal, window, q_offset)
+    b_axes, h_axes = [], []
+    b_div, h_div = q.shape[0], k.shape[1]
+    for name, size in mesh.shape.items():
+        if name in mesh.manual_axes:
+            continue
+        if b_div % size == 0:
+            b_axes.append(name)
+            b_div //= size
+        elif h_div % size == 0:
+            h_axes.append(name)
+            h_div //= size
+    spec = P(tuple(b_axes) or None, tuple(h_axes) or None, None, None)
+    return jax.shard_map(
+        lambda q, k, v: _attention_tpu(q, k, v, causal, window, q_offset),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
+    )(q, k, v)
+
+
 def attention(q, k, v, causal=True, window=None, q_offset=0):
     """[B,H,Sq,Dh] x [B,Hkv,Sk,Dh]^2 -> [B,H,Sq,Dh]; GQA via Hkv | H."""
     sk = k.shape[2]
     if jax.default_backend() == "tpu" and q.shape[2] >= 128:
-        return _attention_tpu(q, k, v, causal, window, q_offset)
+        return _on_mesh(q, k, v, causal, window, q_offset)
     # NOTE (§Perf B2, refuted): routing medium sequences (256 < Sk <= 2k)
     # through chunked_attention was MEASURED WORSE (+7% memory term on
     # whisper prefill) — the per-chunk accumulator rescale traffic exceeds

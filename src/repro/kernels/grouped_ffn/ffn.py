@@ -7,9 +7,14 @@ prefetched and selects the weight slices directly in the BlockSpec
 
 Grid = (token_blocks, ffn_blocks); the ffn dimension is the innermost
 (sequential) axis so the (bm, D) output block accumulates partial
-``(act(x·Wg) * (x·Wu)) · Wd`` contributions across F-slices in f32, keeping
-VMEM at ~3·D·bf·2B per step — sized for v5e's 16 MB VMEM with D=4096,
-bf=256.  MXU alignment: bm, bf multiples of 128 recommended (asserted soft).
+``(act(x·Wg) * (x·Wu)) · Wd`` contributions across F-slices in f32.
+
+VMEM per step is the double-buffered tiles, 2·(bm·D·x + 3·D·bf·w + bm·D·4)
+bytes, plus the f32 temporaries.  With f32 weights at the paper's §V-D block
+(D=4096) and bf=128 the three weight tiles alone take 2·3·4096·128·4 B =
+12 MiB, so the tiles do not fit the compiler's default 16 MiB scoped limit;
+the kernel asks for ``VMEM_LIMIT_BYTES`` of v5e's 128 MiB instead.  bf must
+be a multiple of 128 (the lane width), bm a multiple of 8.
 """
 
 from __future__ import annotations
@@ -20,6 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
+
+#: scoped VMEM the kernel may claim (v5e holds 128 MiB); room for the f32
+#: §V-D tiles with the rest left to the compiler
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def _kernel(eid_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
@@ -51,7 +62,7 @@ def grouped_ffn_blocked(
     *,
     block_tokens: int = 128,
     block_ffn: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     m, d = x.shape
     e, _, f = wg.shape
@@ -73,6 +84,9 @@ def grouped_ffn_blocked(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        interpret=interpret,
+        name="grouped_ffn",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=resolve_interpret(interpret),
     )(block_expert.astype(jnp.int32), x, wg, wu, wd)
     return out.astype(x.dtype)

@@ -25,10 +25,6 @@ from .ffn import grouped_ffn_blocked
 from .ref import grouped_ffn_ref
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _arrange(expert_id: jnp.ndarray, n_experts: int, block: int):
     """Compute padded positions + per-block experts for ragged grouping."""
     n = expert_id.shape[0]
@@ -169,7 +165,6 @@ def _grouped_ffn(x, expert_id, wg, wu, wd, block_tokens, block_ffn):
     y_pad = grouped_ffn_blocked(
         x_pad, blk_expert, wg, wu, wd,
         block_tokens=block_tokens, block_ffn=block_ffn,
-        interpret=_interpret(),
     )
     y = jnp.zeros((n, d), x.dtype).at[order].set(y_pad[pos])
     return jnp.where((expert_id >= 0)[:, None], y, 0)

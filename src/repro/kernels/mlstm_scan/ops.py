@@ -7,17 +7,13 @@ Pallas kernel is the TPU artifact.
 
 from __future__ import annotations
 
-import jax
-
 from .ref import mlstm_scan_ref
 from .scan import mlstm_scan
 
 
 def mlstm_scan_op(q, k, v, ig, lf, *, chunk: int = 64):
-    if jax.default_backend() == "tpu" and q.shape[2] % chunk == 0:
-        return mlstm_scan(q, k, v, ig, lf, chunk=chunk, interpret=False)
     if q.shape[2] % chunk == 0:
-        return mlstm_scan(q, k, v, ig, lf, chunk=chunk, interpret=True)
+        return mlstm_scan(q, k, v, ig, lf, chunk=chunk)
     return mlstm_scan_ref(q, k, v, ig, lf)
 
 

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 _NEG = -1e30
 
 
@@ -117,7 +119,7 @@ def mlstm_scan(
     lf: jnp.ndarray,   # [B, H, S]  log-sigmoid forget gate
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Returns h [B, H, S, dh]; state starts at the zero/m=-30 init."""
     b, hh, s, dh = q.shape
@@ -139,5 +141,5 @@ def mlstm_scan(
             pltpu.VMEM((1, 1), jnp.float32),     # m
         ],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, ig[..., None], lf[..., None])

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 N_SLOTS = 2
 
 
@@ -54,7 +56,7 @@ def relay_copy(
     slot_map: jnp.ndarray | None = None,
     *,
     block_chunk: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Identity copy of [N, D] through a 2-slot VMEM staging pipeline.
 
@@ -81,5 +83,5 @@ def relay_copy(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(slot_map.astype(jnp.int32), x)

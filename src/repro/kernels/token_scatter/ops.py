@@ -1,6 +1,6 @@
 """Jit'd public wrapper for the token gather/pack kernel.
 
-On non-TPU backends the Pallas body runs in interpret mode (Python
+On the CPU the Pallas body runs in interpret mode (Python
 execution, bit-identical semantics); gradients route through the jnp
 reference via ``jax.custom_vjp`` since the gather's VJP is a scatter-add.
 """
@@ -14,13 +14,9 @@ from .ref import token_gather_ref
 from .scatter import token_gather as _token_gather_pallas
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @jax.custom_vjp
 def token_gather(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    return _token_gather_pallas(x, idx, interpret=_interpret())
+    return _token_gather_pallas(x, idx)
 
 
 def _fwd(x, idx):

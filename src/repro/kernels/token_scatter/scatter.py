@@ -22,13 +22,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(idx_ref, x_ref, mask_ref, o_ref):
     o_ref[...] = x_ref[...] * mask_ref[0, 0].astype(x_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def token_gather(x: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool = True):
+def token_gather(x: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool | None = None):
     """out[i] = x[idx[i]] (idx < 0 -> zeros).  x: [N, D], idx: [M] int32."""
     n, d = x.shape
     m = idx.shape[0]
@@ -48,5 +50,5 @@ def token_gather(x: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool = True):
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(safe, x, mask)

@@ -28,11 +28,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.jax_compat import set_mesh
 from repro.jsonio import json_dumps
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, INPUT_SHAPES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import build_model
 from repro.optim import adamw
@@ -107,7 +107,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
 
     ispecs = model.input_specs(shape)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind in ("train",):
             opt_cfg = adamw.AdamWConfig()
             opt_abs = jax.eval_shape(adamw.init, params_abs)
@@ -198,6 +198,7 @@ def main():
     ap.add_argument("--tag", default="", help="suffix for the output json")
     ap.add_argument("--out", default=OUT_DIR)
     args = ap.parse_args()
+    enable_compile_cache()
 
     def _parse_kv(items):
         out = {}

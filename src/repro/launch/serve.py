@@ -119,10 +119,12 @@ def _run_generate(args):
     import numpy as np
 
     from repro.configs.base import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.registry import build_model
     from repro.serve.engine import ServeEngine
     from repro.sharding.context import SINGLE
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -21,6 +21,7 @@ import numpy as np
 from repro.checkpoint import ckpt
 from repro.configs.base import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM, add_modality_stubs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.optim import adamw
 from repro.sharding.context import ParallelContext, SINGLE
@@ -66,6 +67,7 @@ def build_cfg(args):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = build_cfg(args)
     ctx = SINGLE
     model = build_model(cfg, ctx)

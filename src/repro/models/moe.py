@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.jax_compat import shard_map
 from repro.core.moe_comm import MoECommConfig, MoEDispatcher
 from repro.kernels.grouped_ffn.ops import grouped_ffn, grouped_ffn_ref
 from repro.sharding.context import ParallelContext, SINGLE
@@ -176,7 +175,7 @@ def make_moe_ffn(cfg: ModelConfig, ctx: ParallelContext):
         else:
             tok_spec = P(None, None)     # tiny batches: fully replicated
             inner = _inner_masked
-        y = shard_map(
+        y = jax.shard_map(
             inner,
             mesh=ctx.mesh,
             in_specs=(expert_spec, expert_spec, expert_spec,

@@ -64,6 +64,19 @@ class ServeEngine:
         self._step = jax.jit(make_serve_step(self.model))
         self._prefill = jax.jit(make_prefill_scan(self.model))
 
+    def prefill(self, prompts: np.ndarray):
+        """Run [B, P] prompts through a fresh cache in one jitted scan
+        (cache-correct for all families; bit-identical to stepping token by
+        token).  Returns (last-position logits [B, V], cache)."""
+        B, P = prompts.shape
+        if P < 1:
+            raise ValueError("prompts must carry at least one token")
+        shape = InputShape("serve", self.max_len, B, "decode")
+        cache = self.model.init_cache(B, shape)
+        return self._prefill(
+            self.params, cache, jnp.asarray(prompts, dtype=jnp.int32)
+        )
+
     def generate(
         self,
         prompts: np.ndarray,          # [B, P] int32 prompt tokens
@@ -71,18 +84,10 @@ class ServeEngine:
         temperature: float = 0.0,
         seed: int = 0,
     ) -> np.ndarray:
-        B, P = prompts.shape
-        if P < 1:
-            raise ValueError("prompts must carry at least one token")
-        shape = InputShape("serve", self.max_len, B, "decode")
-        cache = self.model.init_cache(B, shape)
+        P = prompts.shape[1]
+        logits, cache = self.prefill(prompts)
         rng = jax.random.PRNGKey(seed)
         out: List[np.ndarray] = []
-        # prefill the whole prompt in one jitted scan (cache-correct for
-        # all families; bit-identical to stepping token by token)
-        logits, cache = self._prefill(
-            self.params, cache, jnp.asarray(prompts, dtype=jnp.int32)
-        )
         # autoregressive decode
         for j in range(n_new):
             if temperature > 0:

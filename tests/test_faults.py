@@ -23,10 +23,7 @@ Pins the robustness surface of ISSUE 6:
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CostModel, ResourceModel, solve_degraded, solve_mwu
 from repro.core.topology import DOWN_CAP, Topology

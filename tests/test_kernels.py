@@ -18,6 +18,17 @@ from repro.kernels.token_scatter.ref import token_gather_ref
 RNG = np.random.default_rng(0)
 
 
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    from repro.kernels import resolve_interpret
+
+    assert resolve_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    assert resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_interpret(True)
+
+
 # --------------------------------------------------------------------------- #
 # token gather (kernel scatter)
 # --------------------------------------------------------------------------- #

@@ -3,10 +3,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property tests fall back to fixed-sample sweeps
-    from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cost import CostModel
 from repro.core.mcf import (

@@ -28,10 +28,7 @@ import sys
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.mcf import solve_direct, solve_mwu
 from repro.core.topology import Topology

@@ -22,10 +22,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve import (
     BUILTIN_SCENARIOS,

@@ -8,10 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property tests fall back to fixed-sample sweeps
-    from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import ckpt
 from repro.configs import ARCH_IDS, get_config
