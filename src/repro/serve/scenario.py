@@ -244,9 +244,11 @@ class ChurnSpec:
 def compile_churn(spec: ChurnSpec, windows: int) -> Tuple[TenantSpec, ...]:
     """Expand a churn spec over a ``windows``-long horizon.
 
-    Fixed draw order — two draws per tenant slot, always taken, even for
-    slots that fall past the horizon — so the schedule is deterministic in
-    (spec, windows) and a longer horizon only *extends* the prefix.
+    Fixed draw order — two draws per tenant slot — so the schedule is
+    deterministic in (spec, windows).  The first slot that would join too
+    late to step ends the schedule: jitter can make a later slot join
+    earlier, and skipping only the late one would let a longer horizon
+    insert it mid-schedule instead of *extending* the prefix.
     """
     rng = np.random.default_rng(spec.seed)
     out: List[TenantSpec] = []
@@ -256,7 +258,7 @@ def compile_churn(spec: ChurnSpec, windows: int) -> Tuple[TenantSpec, ...]:
         join = max(spec.start + i * spec.spacing + j_off, 0)
         life = max(spec.lifetime + l_off, 1)
         if join >= windows - 1:
-            continue  # would never step before teardown
+            break  # would never step before teardown
         out.append(
             TenantSpec(
                 name=f"{spec.name_prefix}-{i:02d}",
