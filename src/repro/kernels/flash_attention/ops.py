@@ -109,7 +109,8 @@ def _on_mesh(q, k, v, causal, window, q_offset):
     kernel runs per shard inside ``shard_map``: attention is independent per
     sequence and per group of heads, so the batch is split over the mesh axes
     it divides, the heads (q and kv in the same contiguous blocks, which keeps
-    the GQA grouping) over those left, and any other axis holds a replica.
+    the GQA grouping) over those left.  An axis of size > 1 that divides
+    neither is refused rather than left to repeat the kernel on each replica.
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
@@ -125,6 +126,11 @@ def _on_mesh(q, k, v, causal, window, q_offset):
         elif h_div % size == 0:
             h_axes.append(name)
             h_div //= size
+        else:
+            raise ValueError(
+                f"flash attention: mesh axis {name!r} ({size}) divides "
+                f"neither the batch ({q.shape[0]}) nor the kv heads "
+                f"({k.shape[1]}) left to split")
     spec = P(tuple(b_axes) or None, tuple(h_axes) or None, None, None)
     return jax.shard_map(
         lambda q, k, v: _attention_tpu(q, k, v, causal, window, q_offset),

@@ -3,8 +3,9 @@
 Checks, each against a plain reference:
 
   1. the NIMBLE dataplane (``nimble``, ``direct``, ``stripe``) and stock
-     ``all_to_all`` move a hot-spot skewed All-to-Allv bit-exactly
-     (``ref_all_to_allv``);
+     ``all_to_all`` move an All-to-Allv bit-exactly (``ref_all_to_allv``),
+     under uniform random counts (many pairs full at once) and under
+     hot-spot skew;
   2. MoE dispatch/combine equals the dense per-token reference under skew;
   3. an expert-parallel train step on a ``(data, model)`` mesh gives a finite
      first-step loss equal to the single-device loss and to ``direct`` mode,
@@ -59,19 +60,28 @@ def hot_spot_counts(n: int, max_chunks: int, hotspot: float) -> np.ndarray:
     return counts
 
 
+def uniform_counts(n: int, max_chunks: int, seed: int = 0) -> np.ndarray:
+    """[n, n] chunk counts drawn uniformly from [0, max_chunks]: arbitrary
+    demand in which many pairs are full at once."""
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, max_chunks + 1, size=(n, n)).astype(np.int32)
+
+
 def _bits(a: np.ndarray) -> np.ndarray:
     """View an array as unsigned ints of its width, for bitwise equality."""
     return np.ascontiguousarray(a).view(f"u{a.dtype.itemsize}")
 
 
-def check_dataplane(n: int, group_size: int, max_chunks: int,
-                    chunk_elems: int, dtype=jnp.float32,
-                    hotspot: float = 0.9) -> bool:
-    """All three dataplane modes and stock all_to_all vs the numpy oracle."""
+def check_dataplane(n: int, group_size: int, counts: np.ndarray,
+                    max_chunks: int, chunk_elems: int, dtype=jnp.float32,
+                    demand: str = "") -> bool:
+    """All three dataplane modes and stock all_to_all vs the numpy oracle,
+    for the [n, n] chunk ``counts`` (each at most ``max_chunks``)."""
     mesh = Mesh(np.array(jax.devices()[:n]), ("x",))
     C, E = max_chunks, chunk_elems
+    counts = np.asarray(counts, dtype=np.int32)
+    assert counts.shape == (n, n) and int(counts.max()) <= C
     rng = np.random.default_rng(0)
-    counts = hot_spot_counts(n, C, hotspot)
     x_all = rng.normal(size=(n, n, C, E)).astype(dtype)
     for s in range(n):
         for d in range(n):
@@ -80,6 +90,7 @@ def check_dataplane(n: int, group_size: int, max_chunks: int,
     x_in = jnp.asarray(x_all.reshape(n * n, C, E))
     c_in = jnp.asarray(counts.reshape(n * n))
     chunk_bytes = E * jnp.dtype(dtype).itemsize
+    full = int((counts == C).sum())
 
     def stock(x, c):
         return (baseline_all_to_all(x, "x"),
@@ -100,6 +111,7 @@ def check_dataplane(n: int, group_size: int, max_chunks: int,
         good = (np.array_equal(_bits(y), _bits(yref))
                 and np.array_equal(r, rref))
         print(f"[selftest] dataplane {name} n={n} G={group_size} "
+              f"demand={demand} full_pairs={full} C={C} "
               f"chunk={chunk_bytes}B {jnp.dtype(dtype).name}: "
               f"{'bit-exact OK' if good else 'FAIL'}")
         ok &= good
@@ -123,8 +135,9 @@ def check_moe_comm(n: int, tokens: int, d_model: int, top_k: int,
     yref = np.zeros_like(toks)
     for j in range(k):
         yref += gw[:, j:j + 1] * toks * (eidx[:, j:j + 1] + 1.0)
-    # f32 rounding of a k-term gated sum; a bf16 payload misses it by ~1e3x
-    tol = 1e-5 * float(np.abs(yref).max())
+    # f32 rounding of a k-term gated sum, relative to the output's scale
+    # (a bf16 payload misses it by ~1e3x), and never looser than 1e-4
+    tol = min(1e-4, 1e-5 * float(np.abs(yref).max()))
     ok = True
     for mode in ["direct", "nimble"]:
         cfg = MoECommConfig(n_devices=n, n_experts=n_experts, d_model=d_model,
@@ -163,13 +176,16 @@ def check_ep_train(cfg, batch: int, seq: int, steps: int = 1,
     """EP train steps (nimble) on a (data, model=EP_SIZE) mesh.
 
     The first step's loss must equal the single-device forward loss of the
-    same params and batch, and the EP forward loss in ``direct`` mode."""
+    same params and batch, and the EP forward loss in ``direct`` mode.  The
+    dispatch buffers hold every assignment (capacity factor EP_SIZE), so no
+    token is dropped and the EP model computes the single-device function."""
     from repro.models.registry import build_model
     from repro.optim import adamw
     from repro.sharding.context import SINGLE, ParallelContext
     from repro.sharding.specs import build_param_shardings
     from repro.train.step import make_train_step
 
+    cfg = dataclasses.replace(cfg, moe_capacity_factor=float(EP_SIZE))
     mesh = make_test_mesh(model=EP_SIZE)
     rng = np.random.default_rng(seed)
     data = {
@@ -206,7 +222,9 @@ def check_ep_train(cfg, batch: int, seq: int, steps: int = 1,
             losses.append(float(metrics["loss"]))
     loss_ep = losses[0]
     good = (all(np.isfinite(losses))
-            and abs(loss_ep - loss_1) < 5e-2
+            # zeroing the expert layer moves it 7e-2, halving the batch
+            # 6e-2 (reduced config on the CPU; PERF.md)
+            and abs(loss_ep - loss_1) < 1e-3
             and abs(loss_ep - loss_direct) < 1e-4
             and n_dev == mesh.devices.size)
     print(f"[selftest] EP train mesh={dict(mesh.shape)} G={EP_SIZE // 2} "
@@ -223,7 +241,11 @@ def main():
     n = len(jax.devices())
     cfg = dataclasses.replace(get_config("granite-moe-1b-a400m").reduced(),
                               n_experts=8, top_k=2)
-    ok = check_dataplane(n, max(1, n // 2), max_chunks=64, chunk_elems=32)
+    G = max(1, n // 2)
+    ok = check_dataplane(n, G, uniform_counts(n, 16), max_chunks=16,
+                         chunk_elems=32, demand="uniform")
+    ok &= check_dataplane(n, G, hot_spot_counts(n, 64, 0.9), max_chunks=64,
+                          chunk_elems=32, demand="hotspot0.9")
     ok &= check_moe_comm(n, tokens=64, d_model=16, top_k=2, n_experts=16,
                          chunk_tokens=4)
     ok &= check_ep_train(cfg, batch=8, seq=32)

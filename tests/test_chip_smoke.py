@@ -58,6 +58,12 @@ def test_forward_phase(granite_small):
     assert kernels == set()          # the CPU compiles no TPU kernel
 
 
+def test_flash_phase(granite_small):
+    """The kernel (interpret mode on the CPU) over two KV blocks."""
+    cfg, _, _ = granite_small
+    assert smoke.phase_flash(cfg, seq=256)
+
+
 def test_planner_phase():
     assert smoke.phase_planner(n=4, group_size=2, max_chunks=32,
                                hotspot=0.9)
@@ -126,7 +132,8 @@ def test_four_chip_phase_on_virtual_devices():
                        capture_output=True, text=True, timeout=580)
     assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
     out = r.stdout
-    assert out.count("bit-exact OK") == 4
+    assert out.count("bit-exact OK") == 8
+    assert out.count("demand=uniform") == 4
     assert out.count("[selftest] moe_comm") == 2 and "FAIL" not in out
     assert "mesh={'data': 1, 'model': 4}" in out
     assert "expert_shard_devices=4 OK" in out

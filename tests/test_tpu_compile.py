@@ -107,6 +107,17 @@ def test_flash_attention_on_four_chip_mesh(topo, monkeypatch, batch):
     assert "flash_attention" in tpu_kernels(compiled.as_text())
 
 
+def test_flash_attention_refuses_an_axis_it_cannot_split(topo, monkeypatch):
+    """A model axis of 4 divides neither a batch of 1 nor 2 kv heads: the
+    kernel is not silently repeated on every device."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    s = jax.ShapeDtypeStruct((1, 2, 128, 64), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    with jax.set_mesh(mesh), pytest.raises(ValueError, match="divides"):
+        jax.jit(lambda q, k, v: attention(q, k, v)).lower(s, s, s)
+
+
 def test_moe_dispatch_combine_four_chips(topo, monkeypatch):
     """Granite's EP expert layer (nimble dispatch -> grouped_ffn -> combine)
     on a (data=1, model=4) mesh of the described chips."""
