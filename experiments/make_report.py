@@ -270,8 +270,7 @@ def obs_section():
         return
     print("\n### Observability (flight recorder)\n")
     print(
-        f"traced drift run ({rec['windows']}w): overhead "
-        f"{rec['overhead_ratio']:.4f}x the untraced loop (gate <= 1.03), "
+        f"traced drift run ({rec['windows']}w): "
         f"recorded arm byte-identical: {rec['identical']}; trace "
         f"{rec['trace_events']} events / {rec['trace_spans']} spans across "
         f"{', '.join(rec['layers'])}; provenance {rec['plans_issued']} "

@@ -43,6 +43,11 @@ from .schedule import (
 )
 from .topology import Topology
 
+#: trace scopes of the stages this module owns (see ``models/moe.py``)
+PLAN = "nimble.plan"
+ROUNDS = "nimble.rounds"
+REASSEMBLE = "nimble.reassemble"
+
 
 def rel_id_of(m: int, dq: int, G: int) -> int:
     """rel enumeration order: m-major, (0,0) skipped."""
@@ -264,8 +269,9 @@ class NimbleAllToAll:
     # -- execution ----------------------------------------------------------------
     def plan_from_counts(self, send_chunks: jnp.ndarray) -> jnp.ndarray:
         """All-gather live counts and plan (endpoint-driven, replicated)."""
-        D = jax.lax.all_gather(send_chunks, self.axis_name)   # [n, n]
-        return self._plan(D)                                  # [n, n, K]
+        with jax.named_scope(PLAN):
+            D = jax.lax.all_gather(send_chunks, self.axis_name)   # [n, n]
+            return self._plan(D)                              # [n, n, K]
 
     def __call__(
         self, x: jnp.ndarray, send_chunks: jnp.ndarray
@@ -286,61 +292,62 @@ class NimbleAllToAll:
         sched = self.sched
         axis = self.axis_name
 
-        me = jax.lax.axis_index(axis)
-        g, p = me // G, me % G
-        rel_m = jnp.asarray(self._rel_m)
-        rel_dq = jnp.asarray(self._rel_dq)
-        dest = ((g + rel_m) % NG) * G + (p + rel_dq) % G      # [n_rel]
-        src = ((g - rel_m) % NG) * G + (p - rel_dq) % G       # [n_rel]
+        with jax.named_scope(ROUNDS):
+            me = jax.lax.axis_index(axis)
+            g, p = me // G, me % G
+            rel_m = jnp.asarray(self._rel_m)
+            rel_dq = jnp.asarray(self._rel_dq)
+            dest = ((g + rel_m) % NG) * G + (p + rel_dq) % G  # [n_rel]
+            src = ((g - rel_m) % NG) * G + (p - rel_dq) % G   # [n_rel]
 
-        my_rel_chunks = chunks[me][dest]                      # [n_rel, K]
-        start = jnp.cumsum(my_rel_chunks, axis=-1) - my_rel_chunks
+            my_rel_chunks = chunks[me][dest]                  # [n_rel, K]
+            start = jnp.cumsum(my_rel_chunks, axis=-1) - my_rel_chunks
 
-        slot_rel = jnp.asarray(sched.slot_rel)
-        slot_k = jnp.asarray(sched.slot_k)
-        slot_pos = jnp.asarray(sched.slot_pos)
+            slot_rel = jnp.asarray(sched.slot_rel)
+            slot_k = jnp.asarray(sched.slot_k)
+            slot_pos = jnp.asarray(sched.slot_pos)
 
-        chunk_idx = start[slot_rel, slot_k] + slot_pos        # [n_slots]
-        valid = slot_pos < my_rel_chunks[slot_rel, slot_k]
-        x_rel = x[dest]                                       # [n_rel, C, E]
-        state = (
-            x_rel[slot_rel, jnp.clip(chunk_idx, 0, self.C - 1)]
-            * valid[:, None].astype(x.dtype)
-        )                                                     # [n_slots, E]
+            chunk_idx = start[slot_rel, slot_k] + slot_pos    # [n_slots]
+            valid = slot_pos < my_rel_chunks[slot_rel, slot_k]
+            x_rel = x[dest]                                   # [n_rel, C, E]
+            state = (
+                x_rel[slot_rel, jnp.clip(chunk_idx, 0, self.C - 1)]
+                * valid[:, None].astype(x.dtype)
+            )                                                 # [n_slots, E]
 
-        # three normalized rounds of uniform hop permutations (§Perf C2:
-        # per-(rel,k) segments move as contiguous slices — no full-state
-        # gather/scatter per round)
-        segs = self._segments
-        state_segs = [
-            jax.lax.slice_in_dim(state, s, e, axis=0)
-            for (_, _, s, e) in segs
-        ]
-        for t in range(len(sched.rounds)):
-            for hop, seg_ids in sorted(self._round_groups[t].items()):
-                sub = jnp.concatenate([state_segs[i] for i in seg_ids],
-                                      axis=0)
-                sub = jax.lax.ppermute(sub, axis, sched.perm_pairs(hop))
-                off = 0
-                for i in seg_ids:
-                    ln = segs[i][3] - segs[i][2]
-                    state_segs[i] = jax.lax.slice_in_dim(
-                        sub, off, off + ln, axis=0)
-                    off += ln
-        state = jnp.concatenate(state_segs, axis=0)
-
-        # per-destination reassembly using the source's (replicated) plan
-        src_rel_chunks = chunks[src, me]                      # [n_rel, K]
-        rstart = jnp.cumsum(src_rel_chunks, axis=-1) - src_rel_chunks
-        recv_idx = rstart[slot_rel, slot_k] + slot_pos
-        rvalid = slot_pos < src_rel_chunks[slot_rel, slot_k]
-        y_rel = jnp.zeros((self.n_rel, self.C, x.shape[-1]), dtype=x.dtype)
-        y_rel = y_rel.at[slot_rel, jnp.clip(recv_idx, 0, self.C - 1)].add(
-            state * rvalid[:, None].astype(x.dtype)
-        )
-        y = jnp.zeros_like(x).at[src].set(y_rel)
-        y = y.at[me].set(x[me])                               # local traffic
-        return y
+            # three normalized rounds of uniform hop permutations (§Perf C2:
+            # per-(rel,k) segments move as contiguous slices — no full-state
+            # gather/scatter per round)
+            segs = self._segments
+            state_segs = [
+                jax.lax.slice_in_dim(state, s, e, axis=0)
+                for (_, _, s, e) in segs
+            ]
+            for t in range(len(sched.rounds)):
+                for hop, seg_ids in sorted(self._round_groups[t].items()):
+                    sub = jnp.concatenate([state_segs[i] for i in seg_ids],
+                                          axis=0)
+                    sub = jax.lax.ppermute(sub, axis, sched.perm_pairs(hop))
+                    off = 0
+                    for i in seg_ids:
+                        ln = segs[i][3] - segs[i][2]
+                        state_segs[i] = jax.lax.slice_in_dim(
+                            sub, off, off + ln, axis=0)
+                        off += ln
+            state = jnp.concatenate(state_segs, axis=0)
+        with jax.named_scope(REASSEMBLE):
+            # per-destination reassembly using the source's (replicated) plan
+            src_rel_chunks = chunks[src, me]                  # [n_rel, K]
+            rstart = jnp.cumsum(src_rel_chunks, axis=-1) - src_rel_chunks
+            recv_idx = rstart[slot_rel, slot_k] + slot_pos
+            rvalid = slot_pos < src_rel_chunks[slot_rel, slot_k]
+            y_rel = jnp.zeros((self.n_rel, self.C, x.shape[-1]), dtype=x.dtype)
+            y_rel = y_rel.at[slot_rel, jnp.clip(recv_idx, 0, self.C - 1)].add(
+                state * rvalid[:, None].astype(x.dtype)
+            )
+            y = jnp.zeros_like(x).at[src].set(y_rel)
+            y = y.at[me].set(x[me])                           # local traffic
+            return y
 
 
 def baseline_all_to_all(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:

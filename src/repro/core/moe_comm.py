@@ -36,6 +36,10 @@ from .dataplane import NimbleAllToAll
 from .planner import PlannerConfig
 from .topology import Topology
 
+#: trace scopes of the stages this module owns (see ``models/moe.py``)
+PACK = "nimble.pack"
+COMBINE = "nimble.combine"
+
 
 @dataclasses.dataclass
 class MoECommConfig:
@@ -196,36 +200,41 @@ class MoEDispatcher:
         C = cap_tok // ct
         comm = self._comm(C, ct * d)
 
-        dest = (expert_idx // cfg.experts_per_device).reshape(A)  # [A]
-        if token_valid is not None:
-            # unowned tokens (replicated-token mode, DESIGN.md §8): route to
-            # a sentinel so they never enter any send buffer.
-            avalid = jnp.repeat(token_valid, k)
-            dest = jnp.where(avalid, dest, n)                      # sentinel
-        # stable pack: position of each assignment within its destination
-        order = jnp.argsort(dest, stable=True)                    # [A]
-        dest_sorted = dest[order]
-        counts = jnp.bincount(dest, length=n)                     # tokens/dest
-        offsets = jnp.cumsum(counts) - counts
-        slot_sorted = jnp.arange(A) - offsets[jnp.minimum(dest_sorted, n - 1)]
-        kept_sorted = (slot_sorted < cap_tok) & (dest_sorted < n)  # cap + owned
-        # scatter assignment a=order[r] -> (dest, slot)
-        slot = jnp.zeros((A,), jnp.int32).at[order].set(slot_sorted.astype(jnp.int32))
-        kept = jnp.zeros((A,), bool).at[order].set(kept_sorted)
+        with jax.named_scope(PACK):
+            dest = (expert_idx // cfg.experts_per_device).reshape(A)  # [A]
+            if token_valid is not None:
+                # unowned tokens (replicated-token mode, DESIGN.md §8):
+                # route to a sentinel so they never enter any send buffer.
+                avalid = jnp.repeat(token_valid, k)
+                dest = jnp.where(avalid, dest, n)                  # sentinel
+            # stable pack: position of each assignment within its destination
+            order = jnp.argsort(dest, stable=True)                # [A]
+            dest_sorted = dest[order]
+            counts = jnp.bincount(dest, length=n)                 # tokens/dest
+            offsets = jnp.cumsum(counts) - counts
+            slot_sorted = (jnp.arange(A)
+                           - offsets[jnp.minimum(dest_sorted, n - 1)])
+            # kept: within capacity and owned
+            kept_sorted = (slot_sorted < cap_tok) & (dest_sorted < n)
+            # scatter assignment a=order[r] -> (dest, slot)
+            slot = jnp.zeros((A,), jnp.int32).at[order].set(
+                slot_sorted.astype(jnp.int32))
+            kept = jnp.zeros((A,), bool).at[order].set(kept_sorted)
 
-        tok_flat = jnp.repeat(tokens, k, axis=0)                  # [A, d]
-        x = jnp.zeros((n, C * ct, d), cfg.payload_dtype)
-        x = x.at[dest, jnp.minimum(slot, cap_tok - 1)].add(
-            jnp.where(kept[:, None], tok_flat.astype(cfg.payload_dtype), 0)
-        )
-        e_side = jnp.full((n, C * ct, 1), -1.0, jnp.float32)
-        e_side = e_side.at[dest, jnp.minimum(slot, cap_tok - 1), 0].set(
-            jnp.where(kept, expert_idx.reshape(A).astype(jnp.float32), -1.0)
-        )
+            tok_flat = jnp.repeat(tokens, k, axis=0)              # [A, d]
+            x = jnp.zeros((n, C * ct, d), cfg.payload_dtype)
+            x = x.at[dest, jnp.minimum(slot, cap_tok - 1)].add(
+                jnp.where(kept[:, None], tok_flat.astype(cfg.payload_dtype), 0)
+            )
+            e_side = jnp.full((n, C * ct, 1), -1.0, jnp.float32)
+            e_side = e_side.at[dest, jnp.minimum(slot, cap_tok - 1), 0].set(
+                jnp.where(kept, expert_idx.reshape(A).astype(jnp.float32),
+                          -1.0)
+            )
 
-        send_chunks = jnp.ceil(
-            jnp.minimum(counts, cap_tok) / ct
-        ).astype(jnp.int32)                                       # [n]
+            send_chunks = jnp.ceil(
+                jnp.minimum(counts, cap_tok) / ct
+            ).astype(jnp.int32)                                   # [n]
         plan = comm.plan_from_counts(send_chunks)                 # [n, n, K]
 
         y = comm.execute(x.reshape(n, C, ct * d), plan)
@@ -283,18 +292,20 @@ class MoEDispatcher:
         C = state["C"]
         comm = self._comm(C, ct * d)
 
-        # transpose plan: what I received per source is what I send back
-        plan_T = jnp.swapaxes(state["plan"], 0, 1)
-        y = comm.execute(
-            expert_out.reshape(n, C, ct * d).astype(cfg.payload_dtype), plan_T
-        )
-        me = jax.lax.axis_index(self.axis)
-        y = y.reshape(n, C, ct, d)
-        y = y.at[me].set(expert_out[me].astype(cfg.payload_dtype))
-        # gather each assignment's processed token from (dest, slot)
-        flat = y.reshape(n, C * ct, d)
-        a_out = flat[state["dest"], jnp.minimum(state["slot"], C * ct - 1)]
-        a_out = jnp.where(state["kept"][:, None], a_out, 0)
-        w = gate_w.reshape(T * k, 1).astype(a_out.dtype)
-        out = (a_out * w).reshape(T, k, d).sum(axis=1)
-        return out
+        with jax.named_scope(COMBINE):
+            # transpose plan: what I received per source is what I send back
+            plan_T = jnp.swapaxes(state["plan"], 0, 1)
+            y = comm.execute(
+                expert_out.reshape(n, C, ct * d).astype(cfg.payload_dtype),
+                plan_T,
+            )
+            me = jax.lax.axis_index(self.axis)
+            y = y.reshape(n, C, ct, d)
+            y = y.at[me].set(expert_out[me].astype(cfg.payload_dtype))
+            # gather each assignment's processed token from (dest, slot)
+            flat = y.reshape(n, C * ct, d)
+            a_out = flat[state["dest"], jnp.minimum(state["slot"], C * ct - 1)]
+            a_out = jnp.where(state["kept"][:, None], a_out, 0)
+            w = gate_w.reshape(T * k, 1).astype(a_out.dtype)
+            out = (a_out * w).reshape(T, k, d).sum(axis=1)
+            return out

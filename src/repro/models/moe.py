@@ -21,11 +21,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.moe_comm import MoECommConfig, MoEDispatcher
+from repro.core.moe_comm import COMBINE, MoECommConfig, MoEDispatcher
 from repro.kernels.grouped_ffn.ops import grouped_ffn, grouped_ffn_ref
 from repro.sharding.context import ParallelContext, SINGLE
 
 from . import layers as L
+
+#: trace scopes of the stages this module owns; each stage's ops carry the
+#: name in their ``op_name`` metadata (``bench/scopes.py`` reads them)
+ROUTE = "nimble.route"
+DISPATCH = "nimble.dispatch"
+FFN = "nimble.ffn"
 
 
 def init(rng, cfg: ModelConfig, ctx: ParallelContext = SINGLE):
@@ -62,28 +68,30 @@ def init(rng, cfg: ModelConfig, ctx: ParallelContext = SINGLE):
 
 def _router(p, xf: jnp.ndarray, cfg: ModelConfig):
     """xf [N, D] -> (top_idx [N,k], top_w [N,k], aux_loss scalar)."""
-    logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                   # [N, E]
-    top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    # switch-style load-balance loss
-    frac = jnp.zeros((cfg.n_experts,), jnp.float32).at[top_idx.reshape(-1)].add(
-        1.0
-    ) / top_idx.size
-    imp = probs.mean(0)
-    aux = cfg.n_experts * jnp.sum(frac * imp)
-    return top_idx.astype(jnp.int32), top_w, aux
+    with jax.named_scope(ROUTE):
+        logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)               # [N, E]
+        top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        # switch-style load-balance loss
+        frac = jnp.zeros((cfg.n_experts,), jnp.float32).at[
+            top_idx.reshape(-1)].add(1.0) / top_idx.size
+        imp = probs.mean(0)
+        aux = cfg.n_experts * jnp.sum(frac * imp)
+        return top_idx.astype(jnp.int32), top_w, aux
 
 
 def _moe_local(p, xf, top_idx, top_w, cfg: ModelConfig):
     """Single-device expert compute via the grouped FFN kernel."""
     n, d = xf.shape
     k = cfg.top_k
-    x_rep = jnp.repeat(xf, k, axis=0)
-    eid = top_idx.reshape(-1)
-    y = grouped_ffn(x_rep, eid, p["wg"], p["wu"], p["wd"],
-                    block_tokens=64, block_ffn=min(128, cfg.d_ff))
-    y = (y.reshape(n, k, d) * top_w[..., None].astype(y.dtype)).sum(1)
+    with jax.named_scope(FFN):
+        x_rep = jnp.repeat(xf, k, axis=0)
+        eid = top_idx.reshape(-1)
+        y = grouped_ffn(x_rep, eid, p["wg"], p["wu"], p["wd"],
+                        block_tokens=64, block_ffn=min(128, cfg.d_ff))
+    with jax.named_scope(COMBINE):
+        y = (y.reshape(n, k, d) * top_w[..., None].astype(y.dtype)).sum(1)
     return y
 
 
@@ -91,12 +99,14 @@ def _moe_ep(p, xf, top_idx, top_w, cfg: ModelConfig, ctx: ParallelContext,
             dispatcher: MoEDispatcher):
     """Expert-parallel path (inside shard_map): NIMBLE dispatch/combine."""
     epd = cfg.n_experts // ctx.ep_size
-    recv, e_local, state = dispatcher.dispatch(xf, top_idx)
+    with jax.named_scope(DISPATCH):
+        recv, e_local, state = dispatcher.dispatch(xf, top_idx)
     n, C, ct, d = recv.shape
-    flat = recv.reshape(n * C * ct, d)
-    eids = e_local.reshape(n * C * ct)
-    y = grouped_ffn(flat, eids, p["wg"], p["wu"], p["wd"],
-                    block_tokens=64, block_ffn=min(128, cfg.d_ff))
+    with jax.named_scope(FFN):
+        flat = recv.reshape(n * C * ct, d)
+        eids = e_local.reshape(n * C * ct)
+        y = grouped_ffn(flat, eids, p["wg"], p["wu"], p["wd"],
+                        block_tokens=64, block_ffn=min(128, cfg.d_ff))
     out = dispatcher.combine(y.reshape(n, C, ct, d), state, top_w)
     return out
 
@@ -151,13 +161,16 @@ def make_moe_ffn(cfg: ModelConfig, ctx: ParallelContext):
         me = jax.lax.axis_index(ctx.model_axis)
         T = xf.shape[0]
         owned = (jnp.arange(T) % ctx.ep_size) == me
-        recv, e_local, state = dispatcher.dispatch(xf, ti, token_valid=owned)
+        with jax.named_scope(DISPATCH):
+            recv, e_local, state = dispatcher.dispatch(xf, ti,
+                                                       token_valid=owned)
         n, C, ct, d = recv.shape
-        y = grouped_ffn(
-            recv.reshape(n * C * ct, d), e_local.reshape(n * C * ct),
-            pp["wg"], pp["wu"], pp["wd"],
-            block_tokens=64, block_ffn=min(128, cfg.d_ff),
-        )
+        with jax.named_scope(FFN):
+            y = grouped_ffn(
+                recv.reshape(n * C * ct, d), e_local.reshape(n * C * ct),
+                pp["wg"], pp["wu"], pp["wd"],
+                block_tokens=64, block_ffn=min(128, cfg.d_ff),
+            )
         out = dispatcher.combine(y.reshape(n, C, ct, d), state, tw)
         return jax.lax.psum(out, ctx.model_axis)
 

@@ -118,16 +118,14 @@ def test_flash_attention_refuses_an_axis_it_cannot_split(topo, monkeypatch):
         jax.jit(lambda q, k, v: attention(q, k, v)).lower(s, s, s)
 
 
-def test_moe_dispatch_combine_four_chips(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def ep_compiled(topo):
     """Granite's EP expert layer (nimble dispatch -> grouped_ffn -> combine)
-    on a (data=1, model=4) mesh of the described chips."""
-    # the expert layer picks its kernel by backend; this process's is the CPU
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on a (data=1, model=4) mesh of the described chips, compiled."""
     cfg = get_config(GRANITE)
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
     ctx = ParallelContext(mesh=mesh, ep_size=4, group_size=2,
                           moe_mode="nimble")
-    apply = moe.make_moe_ffn(cfg, ctx)
     E, d, F = cfg.n_experts, cfg.d_model, cfg.d_ff
 
     def s(shape, spec):
@@ -138,8 +136,60 @@ def test_moe_dispatch_combine_four_chips(topo, monkeypatch):
     p = {"router": s((d, E), P()), "wg": s((E, d, F), ex),
          "wu": s((E, d, F), ex), "wd": s((E, F, d), ex)}
     x = s((4, 256, d), P(None, "model", None))
-    compiled = jax.jit(apply).lower(p, x).compile()
-    text = compiled.as_text()
+    # the expert layer picks its kernel by backend; this process's is the CPU
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        apply = moe.make_moe_ffn(cfg, ctx)
+        return jax.jit(apply).lower(p, x).compile()
+
+
+def test_moe_dispatch_combine_four_chips(ep_compiled):
+    """Granite's EP expert layer (nimble dispatch -> grouped_ffn -> combine)
+    on a (data=1, model=4) mesh of the described chips."""
+    text = ep_compiled.as_text()
     assert "grouped_ffn" in tpu_kernels(text)
     assert "collective-permute" in text        # the NIMBLE rounds
-    assert compiled.memory_analysis() is not None
+    assert ep_compiled.memory_analysis() is not None
+
+
+def test_moe_four_chips_scopes(ep_compiled):
+    """The stages' named scopes reach the TPU compiler's output, where the
+    trace reduction reads them: every exchange hop is in ``nimble.rounds``,
+    the counts' all-gather in ``nimble.plan``, the kernel in ``nimble.ffn``
+    and the send buffer's sort and scatter in ``nimble.pack``."""
+    from bench import scopes    # the trace reduction's reading of HLO text
+
+    ops = [(name, op_name or "") for _, name, op_name, _, _ in
+           scopes.instructions(ep_compiled.as_text())]
+    hops = [n for name, n in ops if name.startswith("collective-permute")]
+    assert hops and all(scopes.scope_of(n) == "nimble.rounds"
+                        for n in hops), hops
+    assert any(n.endswith("nimble.plan/all_gather") for _, n in ops)
+    ffn = [n for name, n in ops if name.startswith("grouped_ffn")]
+    assert ffn and all(scopes.scope_of(n) == "nimble.ffn" for n in ffn), ffn
+    assert any(scopes.scope_of(n) == "nimble.pack" for name, n in ops
+               if name.split(".")[0] in ("sort", "scatter"))
+
+
+def test_moe_one_chip_scopes(one_chip, monkeypatch):
+    """On one chip at the paper's block widths (d 4096, 8 experts of width
+    16384, top-2, 4096 tokens), the router, the FFN and the combine carry
+    their scopes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("paper-moe-8e")
+    E, d, F = cfg.n_experts, cfg.d_model, cfg.d_ff
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=one_chip)
+    p = {"router": s((d, E)), "wg": s((E, d, F)), "wu": s((E, d, F)),
+         "wd": s((E, F, d))}
+    apply = moe.make_moe_ffn(cfg, ParallelContext())
+    text = jax.jit(lambda p, x: apply(p, x[None])[0]).lower(
+        p, s((4096, d))).compile().as_text()
+    from bench import scopes    # the trace reduction's reading of HLO text
+
+    names = [n for _, _, n, _, _ in scopes.instructions(text) if n]
+    found = {scopes.scope_of(n) for n in names}
+    for scope in ("nimble.route", "nimble.ffn", "nimble.combine"):
+        assert scope in found, scope
+    assert any(n.endswith("nimble.ffn/jit(grouped_ffn_blocked)/grouped_ffn/"
+                          "pallas_call") for n in names)
