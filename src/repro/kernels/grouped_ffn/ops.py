@@ -4,9 +4,11 @@
 with ``expert_id[i] in [0, E)`` or ``-1`` for padding rows.  It
 
   1. sorts tokens by expert (stable),
-  2. pads each expert's segment to a multiple of ``block_tokens`` (static
-     worst-case buffer of ``N + E*block_tokens`` rows),
-  3. runs the Pallas blocked kernel with per-block expert ids,
+  2. pads each expert's segment to a multiple of the row tile ``bm``
+     (``ffn.row_tile``: the tallest the shape allows, ``block_tokens`` at
+     least; static worst-case buffer of ``N + E*bm`` rows),
+  3. runs the Pallas blocked kernel with per-tile expert ids and valid-row
+     counts (``tile_plan`` says what it streams and computes),
   4. scatters results back to the original order.
 
 Gradients flow through a jnp-reference VJP (the sort/pad is a permutation;
@@ -17,16 +19,40 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from .ffn import grouped_ffn_blocked
+from .ffn import grouped_ffn_blocked, row_tile, sub_rows
 from .ref import grouped_ffn_ref
 
 
+class TilePlan(NamedTuple):
+    bm: int            # row tile; one expert's weights stream once per tile
+    tiles: int         # tiles holding a valid row (the rest stream nothing)
+    sub_tiles: int     # sub-tiles computed (the rest cost no MXU time)
+    rows: int          # rows computed, valid and padding
+    weight_bytes: int  # expert weight bytes read from HBM
+
+
+def tile_plan(counts: Sequence[int], m: int, E: int, d: int, f: int,
+              itemsize: int) -> TilePlan:
+    """What the kernel streams and computes for ``m`` routed rows whose
+    per-expert ``counts`` are known on the host, with the row tile the
+    kernel itself picks (``row_tile``) at the model's blocks: a 64-row
+    floor and 128-wide ffn slices."""
+    bm = row_tile(m, E, d, 128, itemsize, itemsize, floor=64)
+    sub = sub_rows(bm)
+    tiles = sum(-(-int(c) // bm) for c in counts)
+    sub_tiles = sum(-(-int(c) // sub) for c in counts)
+    return TilePlan(bm, tiles, sub_tiles, sub_tiles * sub,
+                    tiles * 3 * d * f * itemsize)
+
+
 def _arrange(expert_id: jnp.ndarray, n_experts: int, block: int):
-    """Compute padded positions + per-block experts for ragged grouping."""
+    """Compute padded positions, per-block experts and per-block valid rows
+    for ragged grouping; blocks holding rows come first."""
     n = expert_id.shape[0]
     m_pad = (-(-n // block) + n_experts) * block  # block-aligned worst case
     key = jnp.where(expert_id < 0, n_experts, expert_id)
@@ -45,7 +71,9 @@ def _arrange(expert_id: jnp.ndarray, n_experts: int, block: int):
         aligned_off[None, :] <= blk_start[:, None], axis=1
     ) - 1
     blk_expert = jnp.clip(blk_expert, 0, n_experts - 1)
-    return order, pos_sorted, blk_expert, m_pad
+    blk_rows = counts[blk_expert] - (blk_start - aligned_off[blk_expert])
+    blk_rows = jnp.clip(blk_rows, 0, block).astype(jnp.int32)
+    return order, pos_sorted, blk_expert, blk_rows, m_pad
 
 
 def grouped_ffn_scan(
@@ -63,7 +91,7 @@ def grouped_ffn_scan(
     dry-run rooflines are faithful); native autodiff."""
     E = wg.shape[0]
     n, d = x.shape
-    order, pos, blk_expert, m_pad = _arrange(expert_id, E, block_tokens)
+    order, pos, blk_expert, _, m_pad = _arrange(expert_id, E, block_tokens)
     x_pad = jnp.zeros((m_pad, d), x.dtype).at[pos].set(x[order])
     xb = x_pad.reshape(-1, block_tokens, d)
 
@@ -137,6 +165,8 @@ def grouped_ffn(
     block_ffn: int = 128,
     cap_factor: float = 2.0,
 ) -> jnp.ndarray:
+    """``block_tokens`` is the CPU paths' block and the kernel's least row
+    tile; the kernel grows its tile from the shape (``ffn.row_tile``)."""
     if jax.default_backend() != "tpu" and x.shape[0] > 4 * block_tokens:
         # §Perf C1: dense segment einsum by default; the block-scan baseline
         # stays selectable for before/after measurement.  Dense wins when
@@ -160,12 +190,15 @@ def grouped_ffn(
 def _grouped_ffn(x, expert_id, wg, wu, wd, block_tokens, block_ffn):
     E = wg.shape[0]
     n, d = x.shape
-    order, pos, blk_expert, m_pad = _arrange(expert_id, E, block_tokens)
+    bm = row_tile(n, E, d, block_ffn, x.dtype.itemsize, wg.dtype.itemsize,
+                  floor=block_tokens)
+    order, pos, blk_expert, blk_rows, m_pad = _arrange(expert_id, E, bm)
     x_pad = jnp.zeros((m_pad, d), x.dtype).at[pos].set(x[order])
     y_pad = grouped_ffn_blocked(
-        x_pad, blk_expert, wg, wu, wd,
-        block_tokens=block_tokens, block_ffn=block_ffn,
+        x_pad, blk_expert, blk_rows, wg, wu, wd,
+        block_tokens=bm, block_ffn=block_ffn,
     )
+    # invalid rows read the last tile, which the kernel never writes
     y = jnp.zeros((n, d), x.dtype).at[order].set(y_pad[pos])
     return jnp.where((expert_id >= 0)[:, None], y, 0)
 
@@ -189,4 +222,5 @@ def _bwd(block_tokens, block_ffn, res, g):
 
 _grouped_ffn.defvjp(_fwd, _bwd)
 
-__all__ = ["grouped_ffn", "grouped_ffn_dense", "grouped_ffn_ref"]
+__all__ = ["TilePlan", "grouped_ffn", "grouped_ffn_dense", "grouped_ffn_ref",
+           "tile_plan"]

@@ -9,7 +9,9 @@ from repro.kernels.flash_attention.flash import flash_attention
 from repro.kernels.flash_attention.ops import chunked_attention
 from repro.kernels.flash_attention.ref import mha_ref
 from repro.kernels.grouped_ffn.ffn import grouped_ffn_blocked
-from repro.kernels.grouped_ffn.ops import grouped_ffn, grouped_ffn_scan
+from repro.kernels.grouped_ffn.ops import (
+    _grouped_ffn, grouped_ffn, grouped_ffn_scan, tile_plan,
+)
 from repro.kernels.grouped_ffn.ref import grouped_ffn_ref
 from repro.kernels.relay_copy.relay import relay_copy
 from repro.kernels.token_scatter.ops import token_gather
@@ -71,17 +73,56 @@ def _ffn_inputs(N, D, F, E, dtype=np.float32):
     return map(jnp.asarray, (x, eid, wg, wu, wd))
 
 
-@pytest.mark.parametrize("N,D,F,E,bt,bf", [
-    (128, 32, 64, 2, 32, 32),
-    (200, 64, 128, 4, 32, 64),
-    (64, 16, 32, 8, 16, 16),
+def _routing(kind, N, E):
+    """Expert ids for ``N`` rows: random (some ``-1``), or a named skew."""
+    if kind == "random":
+        return RNG.integers(-1, E, size=(N,))
+    if kind == "empty_expert":      # expert 1 gets no row
+        return RNG.choice([e for e in range(E) if e != 1], size=(N,))
+    if kind == "one_expert":
+        return np.full((N,), E - 1)
+    if kind == "all_invalid":
+        return np.full((N,), -1)
+    if kind == "ragged":            # counts off the 128-row sub-tile
+        eid = np.full((N,), -1)
+        eid[:300], eid[300:301], eid[301:901] = 0, E - 1, 1
+        return RNG.permutation(eid)
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("N,D,F,E,bt,bf,routing", [
+    (128, 32, 64, 2, 32, 32, "random"),
+    (200, 64, 128, 4, 32, 64, "random"),
+    (64, 16, 32, 8, 16, 16, "random"),
+    (200, 32, 64, 4, 16, 32, "empty_expert"),
+    (256, 32, 64, 4, 16, 32, "one_expert"),
+    (1536, 16, 32, 3, 32, 32, "ragged"),
+    (96, 32, 64, 2, 16, 32, "all_invalid"),
+    (2048, 16, 32, 2, 64, 32, "random"),
 ])
-def test_grouped_ffn_pallas(N, D, F, E, bt, bf):
-    x, eid, wg, wu, wd = _ffn_inputs(N, D, F, E)
-    y = grouped_ffn(x, eid, wg, wu, wd, block_tokens=bt, block_ffn=bf)
+def test_grouped_ffn_pallas(N, D, F, E, bt, bf, routing):
+    """The kernel's path (row tile, sub-tile and empty-tile skipping) in
+    interpret mode, whatever size the CPU dispatch would send elsewhere."""
+    x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
+    eid = jnp.asarray(_routing(routing, N, E), jnp.int32)
+    y = _grouped_ffn(x, eid, wg, wu, wd, bt, bf)
     ref = grouped_ffn_ref(x, eid, wg, wu, wd)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
+
+
+def test_tile_plan():
+    """The one-chip cell's exact routing (4096 tokens, top-2, hot ratio 0.9)
+    at the §V-D widths, f32: 512-row tiles, 22 streamed of 24, 65 sub-tiles;
+    a 32-row decode call keeps the 64-row floor."""
+    counts = [3744, 635, 635, 636, 637, 635, 635, 635]
+    plan = tile_plan(counts, 8192, 8, 4096, 16384, 4)
+    assert plan.bm == 512
+    assert plan.tiles == 22
+    assert plan.rows == 8320 and plan.sub_tiles == 65
+    assert plan.weight_bytes == 22 * 3 * 4096 * 16384 * 4   # 17.7 GB
+    decode = tile_plan([20, 12], 32, 8, 4096, 16384, 4)
+    assert decode.bm == 64 and decode.tiles == 2 and decode.rows == 128
 
 
 def test_grouped_ffn_scan_matches_ref():

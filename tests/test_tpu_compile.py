@@ -20,7 +20,7 @@ from repro.configs.base import get_config
 from repro.kernels import tpu_kernels
 from repro.kernels.flash_attention.flash import flash_attention
 from repro.kernels.flash_attention.ops import attention
-from repro.kernels.grouped_ffn.ffn import grouped_ffn_blocked
+from repro.kernels.grouped_ffn.ffn import grouped_ffn_blocked, row_tile
 from repro.models import moe
 from repro.sharding.context import ParallelContext
 
@@ -43,33 +43,42 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _grouped_ffn_compile(one_chip, m, d, f, e, dtype):
+def _grouped_ffn_compile(one_chip, n, d, f, e, dtype):
+    """The kernel for ``n`` routed rows, at the row tile it picks for them
+    and the padded buffer ``grouped_ffn`` hands it."""
     s = lambda shape, t: jax.ShapeDtypeStruct(shape, t, sharding=one_chip)
-    bt, bf = 64, 128
+    bf = 128
+    bt = row_tile(n, e, d, bf, jnp.dtype(dtype).itemsize,
+                  jnp.dtype(dtype).itemsize)
+    m = (-(-n // bt) + e) * bt
 
-    def fn(x, blk, wg, wu, wd):
-        return grouped_ffn_blocked(x, blk, wg, wu, wd, block_tokens=bt,
+    def fn(x, blk, rows, wg, wu, wd):
+        return grouped_ffn_blocked(x, blk, rows, wg, wu, wd, block_tokens=bt,
                                    block_ffn=bf, interpret=False)
 
-    return jax.jit(fn).lower(
-        s((m, d), dtype), s((m // bt,), jnp.int32), s((e, d, f), dtype),
-        s((e, d, f), dtype), s((e, f, d), dtype)).compile()
+    compiled = jax.jit(fn).lower(
+        s((m, d), dtype), s((m // bt,), jnp.int32), s((m // bt,), jnp.int32),
+        s((e, d, f), dtype), s((e, d, f), dtype), s((e, f, d), dtype),
+    ).compile()
+    return bt, compiled
 
 
 def test_grouped_ffn_granite_widths(one_chip):
     cfg = get_config(GRANITE)
-    m = 2048 * cfg.top_k + cfg.n_experts * 64     # S=2048 forward, padded
-    compiled = _grouped_ffn_compile(one_chip, m, cfg.d_model, cfg.d_ff,
-                                    cfg.n_experts, jnp.float32)
+    _, compiled = _grouped_ffn_compile(          # S=2048 forward
+        one_chip, 2048 * cfg.top_k, cfg.d_model, cfg.d_ff, cfg.n_experts,
+        jnp.float32)
     assert "grouped_ffn" in tpu_kernels(compiled.as_text())
 
 
 def test_grouped_ffn_paper_block_f32(one_chip):
-    """§V-D block (d=4096, F=16384, E=8) with f32 weights: the tiles need
-    more than the compiler's default scoped VMEM."""
+    """§V-D block (d=4096, F=16384, E=8) with f32 weights, 8192 routed rows
+    (4096 tokens, top-2): the 512-row tiles the kernel picks fit its scoped
+    VMEM, which is more than the compiler's default."""
     cfg = get_config("paper-moe-8e")
-    compiled = _grouped_ffn_compile(one_chip, 4096, cfg.d_model, cfg.d_ff,
-                                    cfg.n_experts, jnp.float32)
+    bt, compiled = _grouped_ffn_compile(one_chip, 8192, cfg.d_model,
+                                        cfg.d_ff, cfg.n_experts, jnp.float32)
+    assert bt == 512
     assert "grouped_ffn" in tpu_kernels(compiled.as_text())
 
 
