@@ -30,6 +30,11 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     moe_capacity_factor: float = 2.0
+    n_shared_experts: int = 0    # SwiGLU of width n_shared * d_ff on every token
+    router_score: str = "softmax"  # softmax | sigmoid (bias-corrected top-k)
+    routed_scale: float = 1.0    # sigmoid router: factor on the renormalized gates
+    first_dense_layers: int = 0  # leading layers with a dense FFN, no router
+    d_ff_dense: int = 0          # their FFN width
     # ssm / hybrid
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -44,6 +49,11 @@ class ModelConfig:
     # attention details
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # multi-head latent attention (kv_lora_rank > 0; else GQA)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     window: Optional[int] = None  # sliding-window size (sub-quadratic mode)
     # enc-dec (audio)
     n_enc_layers: int = 0
@@ -89,6 +99,12 @@ class ModelConfig:
             attn_every=min(self.attn_every, 2) if self.attn_every else 0,
             window=min(self.window, 64) if self.window else None,
             head_dim_override=None,
+            first_dense_layers=min(self.first_dense_layers, 1),
+            d_ff_dense=max(64, d * 2) if self.d_ff_dense else 0,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 16),
         )
 
 
@@ -128,6 +144,7 @@ ARCH_IDS: List[str] = [
     "xlstm-125m",
     "smollm-135m",
     "whisper-small",
+    "moonlight-16b-a3b",
     # the paper's own evaluation model (§V-D): 8-expert MoE block testbed
     "paper-moe-8e",
 ]

@@ -7,7 +7,9 @@ makes ``long_500k`` runnable for full-attention models (DESIGN.md §7).
 Grid = (batch, heads, q_blocks, kv_blocks); kv is innermost/sequential so the
 running (m, l, acc) statistics live in VMEM scratch across kv steps.  GQA is
 expressed in the BlockSpec index_map (query head h reads kv head h // g) —
-no repeated KV in HBM.  Block shapes default to (128, 128), MXU-aligned.
+no repeated KV in HBM.  Values may be narrower than queries and keys (latent
+attention's 128 against 192).  Block shapes default to (128, 128),
+MXU-aligned.
 """
 
 from __future__ import annotations
@@ -37,7 +39,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     q = q_ref[0, 0].astype(jnp.float32)          # [bq, dh]
     k = k_ref[0, 0].astype(jnp.float32)          # [bk, dh]
-    v = v_ref[0, 0].astype(jnp.float32)          # [bk, dh]
+    v = v_ref[0, 0].astype(jnp.float32)          # [bk, dv]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                  # [bq, bk]
@@ -74,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 def flash_attention(
     q: jnp.ndarray,   # [B, H, Sq, Dh]
     k: jnp.ndarray,   # [B, Hkv, Sk, Dh]
-    v: jnp.ndarray,   # [B, Hkv, Sk, Dh]
+    v: jnp.ndarray,   # [B, Hkv, Sk, Dv]
     *,
     causal: bool = True,
     window: int | None = None,
@@ -84,7 +86,7 @@ def flash_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     b, h, sq, dh = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[3]
     g = h // hkv
     bq = min(bq, sq)
     bk = min(bk, sk)
@@ -102,17 +104,17 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((1, 1, bq, dh), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, dh), lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
-            pl.BlockSpec((1, 1, bk, dh), lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, dv), lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, bq, dh), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+            (1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
         name="flash_attention",
         interpret=resolve_interpret(interpret),
     )(q, k, v)

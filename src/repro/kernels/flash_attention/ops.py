@@ -35,7 +35,7 @@ def chunked_attention(
 ) -> jnp.ndarray:
     """Online-softmax over kv chunks (lax.scan) — flash semantics in jnp."""
     b, h, sq, dh = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[3]
     g = h // hkv
     nc = -(-sk // chunk)
     pad = nc * chunk - sk
@@ -43,7 +43,7 @@ def chunked_attention(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kc = k.reshape(b, hkv, nc, chunk, dh).transpose(2, 0, 1, 3, 4)
-    vc = v.reshape(b, hkv, nc, chunk, dh).transpose(2, 0, 1, 3, 4)
+    vc = v.reshape(b, hkv, nc, chunk, dv).transpose(2, 0, 1, 3, 4)
     qf = q.astype(jnp.float32) / (dh ** 0.5)
     qpos = jnp.arange(sq) + q_offset
 
@@ -73,7 +73,7 @@ def chunked_attention(
 
     m0 = jnp.full((b, h, sq), -1e30, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    a0 = jnp.zeros((b, h, sq, dh), jnp.float32)
+    a0 = jnp.zeros((b, h, sq, dv), jnp.float32)
     (m, l, acc, _), _ = jax.lax.scan(step, (m0, l0, a0, jnp.int32(0)), (kc, vc))
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
@@ -139,7 +139,8 @@ def _on_mesh(q, k, v, causal, window, q_offset):
 
 
 def attention(q, k, v, causal=True, window=None, q_offset=0):
-    """[B,H,Sq,Dh] x [B,Hkv,Sk,Dh]^2 -> [B,H,Sq,Dh]; GQA via Hkv | H."""
+    """[B,H,Sq,Dh] x [B,Hkv,Sk,Dh] x [B,Hkv,Sk,Dv] -> [B,H,Sq,Dv]; GQA via
+    Hkv | H."""
     sk = k.shape[2]
     if jax.default_backend() == "tpu" and q.shape[2] >= 128:
         return _on_mesh(q, k, v, causal, window, q_offset)

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 def mha_ref(
     q: jnp.ndarray,   # [B, H, Sq, Dh]
     k: jnp.ndarray,   # [B, Hkv, Sk, Dh]
-    v: jnp.ndarray,   # [B, Hkv, Sk, Dh]
+    v: jnp.ndarray,   # [B, Hkv, Sk, Dv]
     *,
     causal: bool = True,
     window: int | None = None,   # attend to [pos-window+1, pos]

@@ -1,4 +1,5 @@
-"""Shared model layers: norms, RoPE, GQA attention (+caches), SwiGLU.
+"""Shared model layers: norms, RoPE, GQA and latent attention (+caches),
+SwiGLU.
 
 Functional style: ``init_*(rng, ...) -> params`` (nested dicts of arrays)
 and pure apply functions.  Layer stacks are scanned (stacked params with a
@@ -16,6 +17,11 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.ops import attention as flash_attention
 
 Params = Dict[str, jnp.ndarray]
+
+#: trace scope of the latent attention, all of it (``bench/scopes.py``)
+ATTN = "nimble.attn"
+#: RMSNorm epsilon of the latent kv (DeepSeek-V3's ``kv_a_layernorm``)
+KV_NORM_EPS = 1e-6
 
 
 # --------------------------------------------------------------------------- #
@@ -135,11 +141,12 @@ def attention_forward(
 
 
 def init_kv_cache(batch: int, n_kv: int, cache_len: int, head_dim: int,
-                  dtype) -> Params:
-    """Ring-buffer KV cache.  ``cache_len`` = window for SWA, seq for full."""
+                  dtype, v_dim: Optional[int] = None) -> Params:
+    """Ring-buffer KV cache.  ``cache_len`` = window for SWA, seq for full;
+    values are ``v_dim`` wide where that differs from the keys."""
     return {
         "k": jnp.zeros((batch, n_kv, cache_len, head_dim), dtype),
-        "v": jnp.zeros((batch, n_kv, cache_len, head_dim), dtype),
+        "v": jnp.zeros((batch, n_kv, cache_len, v_dim or head_dim), dtype),
         "slot_pos": jnp.full((cache_len,), -1, jnp.int32),  # absolute pos
     }
 
@@ -156,29 +163,98 @@ def attention_decode(
     rope_theta: float | None,
 ) -> Tuple[jnp.ndarray, Params]:
     """One decode step against a ring-buffer cache (RoPE at write time)."""
-    b = x.shape[0]
-    W = cache["k"].shape[2]
     q, k, v = _project_qkv(p, x, n_heads, n_kv, head_dim)   # [B,H,1,dh]
     if rope_theta is not None:
         ppos = pos[None] if pos.ndim == 0 else pos
         q = apply_rope(q, ppos, rope_theta)
         k = apply_rope(k, ppos, rope_theta)
+    o, cache = _cache_attend(q, k, v, cache, pos)
+    return o @ p["wo"], cache
+
+
+def _cache_attend(q, k, v, cache: Params, pos) -> Tuple[jnp.ndarray, Params]:
+    """Write k, v [B, Hkv, 1, *] into the ring buffer at ``pos`` and attend
+    q [B, H, 1, dk] over the cache: -> ([B, 1, H * dv], cache')."""
+    b, n_heads = q.shape[:2]
+    W = cache["k"].shape[2]
     slot = jnp.mod(pos, W)                                   # ring write
     ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, slot, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, slot, 0))
     spos = cache["slot_pos"].at[slot].set(pos.astype(jnp.int32))
 
-    g = n_heads // n_kv
-    kk = jnp.repeat(ck, g, axis=1).astype(jnp.float32)       # [B,H,W,dh]
+    g = n_heads // ck.shape[1]
+    kk = jnp.repeat(ck, g, axis=1).astype(jnp.float32)       # [B,H,W,dk]
     vv = jnp.repeat(cv, g, axis=1).astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kk)
-    s = s / math.sqrt(head_dim)
+    s = s / math.sqrt(q.shape[-1])
     valid = (spos >= 0) & (spos <= pos)                      # [W]
     s = jnp.where(valid[None, None, None, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bhkd->bhqd", w, vv).astype(x.dtype)
-    o = o.transpose(0, 2, 1, 3).reshape(b, 1, n_heads * head_dim)
-    return o @ p["wo"], {"k": ck, "v": cv, "slot_pos": spos}
+    o = jnp.einsum("bhqk,bhkd->bhqd", w, vv).astype(q.dtype)
+    o = o.transpose(0, 2, 1, 3).reshape(b, 1, n_heads * vv.shape[-1])
+    return o, {"k": ck, "v": cv, "slot_pos": spos}
+
+
+# --------------------------------------------------------------------------- #
+# multi-head latent attention (DeepSeek-V3; no query compression)
+# --------------------------------------------------------------------------- #
+
+
+def init_mla(rng, cfg, dtype) -> Params:
+    """q [D, H*(nope+rope)]; kv_a [D, rank+rope] (the latent and the one
+    rope key all heads share); kv_b [rank, H*(nope+v)]; o [H*v, D]."""
+    H, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    ks = jax.random.split(rng, 4)
+    return {
+        "wq": dense_init(ks[0], cfg.d_model, H * (nope + rope), dtype),
+        "wkv_a": dense_init(ks[1], cfg.d_model, rank + rope, dtype),
+        "kv_norm": jnp.ones((rank,), dtype),
+        "wkv_b": dense_init(ks[2], rank, H * (nope + dv), dtype),
+        "wo": dense_init(ks[3], H * dv, cfg.d_model, dtype),
+    }
+
+
+def _mla_qkv(p: Params, x, pos, cfg):
+    """x [B, S, D] at positions ``pos`` [S] -> q, k [B, H, S, nope+rope] and
+    v [B, H, S, v]: RoPE on the rope parts only, k's shared by all heads."""
+    b, s, _ = x.shape
+    H, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (x @ p["wq"]).reshape(b, s, H, nope + rope).transpose(0, 2, 1, 3)
+    ckv = x @ p["wkv_a"]
+    kv = rms_norm(ckv[..., :rank], p["kv_norm"], KV_NORM_EPS) @ p["wkv_b"]
+    kv = kv.reshape(b, s, H, -1).transpose(0, 2, 1, 3)
+    k_pe = apply_rope(ckv[:, None, :, rank:], pos, cfg.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], pos, cfg.rope_theta)], -1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, H, s, rope))], -1)
+    return q, k, kv[..., nope:]
+
+
+def mla_forward(p: Params, x: jnp.ndarray, cfg, *,
+                window: Optional[int] = None,
+                pos_offset: int = 0) -> jnp.ndarray:
+    """Full-sequence latent attention (training / prefill path, flash
+    kernel with value width v, scores scaled by 1/sqrt(nope+rope))."""
+    b, s, _ = x.shape
+    with jax.named_scope(ATTN):
+        q, k, v = _mla_qkv(p, x, jnp.arange(s) + pos_offset, cfg)
+        o = flash_attention(q, k, v, True, window, pos_offset)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        return o @ p["wo"]
+
+
+def mla_decode(p: Params, x: jnp.ndarray, cache: Params, pos: jnp.ndarray,
+               cfg) -> Tuple[jnp.ndarray, Params]:
+    """One decode step; the ring buffer holds each head's full k and v, not
+    the latent."""
+    with jax.named_scope(ATTN):
+        q, k, v = _mla_qkv(p, x, pos[None] if pos.ndim == 0 else pos, cfg)
+        o, cache = _cache_attend(q, k, v, cache, pos)
+        return o @ p["wo"], cache
 
 
 # --------------------------------------------------------------------------- #

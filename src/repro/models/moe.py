@@ -1,13 +1,18 @@
-"""MoE transformer (qwen3-moe / granite-moe / paper-moe-8e).
+"""MoE transformer (qwen3-moe / granite-moe / paper-moe-8e / moonlight).
 
 Same GQA+RoPE skeleton as ``dense.py`` with the FFN replaced by a top-k
-routed expert layer.  Expert parallelism is where the paper's technique
+routed expert layer.  Where the config asks for them: latent attention
+(``kv_lora_rank``), leading dense layers (``first_dense_layers``), shared
+experts beside the routed ones (``n_shared_experts``) and DeepSeek-V3's
+sigmoid router with a selection bias (``router_score="sigmoid"``).  Expert parallelism is where the paper's technique
 lives: with ``ctx.ep_size > 1`` the dispatch/combine All-to-Allv runs
 through :class:`repro.core.MoEDispatcher` (NIMBLE planner + scheduled
 multi-path dataplane) inside ``shard_map`` over the model axis; single
 device falls back to local grouped FFN (CPU smoke tests).
 
 Router: softmax top-k with renormalized gates + switch-style load-balance
+auxiliary loss; or sigmoid scores whose top-k after adding a per-expert bias
+are chosen, weighted by their own scores renormalized and scaled, with no
 auxiliary loss.  No capacity cap at the router (DeepSeek-style no-drop,
 §V-D); the dispatcher's buffer capacity factor is the physical bound.
 """
@@ -32,6 +37,14 @@ from . import layers as L
 ROUTE = "nimble.route"
 DISPATCH = "nimble.dispatch"
 FFN = "nimble.ffn"
+SHARED = "nimble.shared"
+
+
+def _init_attn(r, cfg: ModelConfig, dt):
+    if cfg.kv_lora_rank:
+        return L.init_mla(r, cfg, dt)
+    return L.init_attention(r, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                            cfg.head_dim, dt, cfg.qkv_bias)
 
 
 def init(rng, cfg: ModelConfig, ctx: ParallelContext = SINGLE):
@@ -41,12 +54,9 @@ def init(rng, cfg: ModelConfig, ctx: ParallelContext = SINGLE):
     def init_block(r):
         r1, r2, r3 = jax.random.split(r, 3)
         ks = jax.random.split(r2, 3)
-        return {
+        p = {
             "ln1": jnp.ones((cfg.d_model,), dt),
-            "attn": L.init_attention(
-                r1, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
-                dt, cfg.qkv_bias,
-            ),
+            "attn": _init_attn(r1, cfg, dt),
             "ln2": jnp.ones((cfg.d_model,), dt),
             "router": L.dense_init(r3, cfg.d_model, cfg.n_experts, dt),
             "wg": jax.vmap(lambda k: L.dense_init(k, cfg.d_model, cfg.d_ff, dt))(
@@ -56,20 +66,41 @@ def init(rng, cfg: ModelConfig, ctx: ParallelContext = SINGLE):
             "wd": jax.vmap(lambda k: L.dense_init(k, cfg.d_ff, cfg.d_model, dt))(
                 jax.random.split(ks[2], cfg.n_experts)),
         }
+        if cfg.router_score == "sigmoid":
+            p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+        if cfg.n_shared_experts:
+            p["shared"] = L.init_swiglu(jax.random.fold_in(r3, 1), cfg.d_model,
+                                        cfg.n_shared_experts * cfg.d_ff, dt)
+        return p
 
-    blocks = jax.vmap(init_block)(jax.random.split(k_blocks, cfg.n_layers))
-    return {
+    def init_dense_block(r):
+        r1, r2 = jax.random.split(r)
+        return {
+            "ln1": jnp.ones((cfg.d_model,), dt),
+            "attn": _init_attn(r1, cfg, dt),
+            "ln2": jnp.ones((cfg.d_model,), dt),
+            "mlp": L.init_swiglu(r2, cfg.d_model, cfg.d_ff_dense, dt),
+        }
+
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+    params = {
         "embed": L.embed_init(k_embed, cfg.vocab, cfg.d_model, dt),
-        "blocks": blocks,
+        "blocks": jax.vmap(init_block)(jax.random.split(k_blocks, n_moe)),
         "final_norm": jnp.ones((cfg.d_model,), dt),
         "lm_head": L.dense_init(k_head, cfg.d_model, cfg.vocab, dt),
     }
+    if cfg.first_dense_layers:
+        params["dense_blocks"] = jax.vmap(init_dense_block)(jax.random.split(
+            jax.random.fold_in(k_blocks, 1), cfg.first_dense_layers))
+    return params
 
 
 def _router(p, xf: jnp.ndarray, cfg: ModelConfig):
     """xf [N, D] -> (top_idx [N,k], top_w [N,k], aux_loss scalar)."""
     with jax.named_scope(ROUTE):
         logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
+        if cfg.router_score == "sigmoid":
+            return _sigmoid_topk(p, logits, cfg)
         probs = jax.nn.softmax(logits, axis=-1)               # [N, E]
         top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
         top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
@@ -79,6 +110,25 @@ def _router(p, xf: jnp.ndarray, cfg: ModelConfig):
         imp = probs.mean(0)
         aux = cfg.n_experts * jnp.sum(frac * imp)
         return top_idx.astype(jnp.int32), top_w, aux
+
+
+def _sigmoid_topk(p, logits, cfg: ModelConfig):
+    """DeepSeek-V3's router with one group (noaux_tc): the top-k of
+    sigmoid(logits) + bias are chosen, and their sigmoid scores, renormalized
+    to sum 1 and scaled by ``routed_scale``, weight them.  The bias chooses
+    and never weights; there is no auxiliary loss."""
+    scores = jax.nn.sigmoid(logits)                           # [N, E] f32
+    _, top_idx = jax.lax.top_k(scores + p["router_bias"], cfg.top_k)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    return top_idx.astype(jnp.int32), top_w, jnp.float32(0.0)
+
+
+def _shared_ffn(p, xf):
+    """The shared experts: one SwiGLU of width ``n_shared_experts * d_ff``
+    over every token."""
+    with jax.named_scope(SHARED):
+        return L.swiglu(p["shared"], xf)
 
 
 def _moe_local(p, xf, top_idx, top_w, cfg: ModelConfig):
@@ -119,6 +169,8 @@ def make_moe_ffn(cfg: ModelConfig, ctx: ParallelContext):
             xf = x.reshape(-1, d)
             ti, tw, aux = _router(p, xf, cfg)
             y = _moe_local(p, xf, ti, tw, cfg)
+            if cfg.n_shared_experts:
+                y = y + _shared_ffn(p, xf)
             return y.reshape(b, s, d).astype(x.dtype), aux
         return apply
 
@@ -196,22 +248,45 @@ def make_moe_ffn(cfg: ModelConfig, ctx: ParallelContext):
             out_specs=tok_spec,
             check_vma=False,
         )(p["wg"], p["wu"], p["wd"], xf, ti, tw)
+        if cfg.n_shared_experts:    # on the token-sharded input, outside
+            y = y + _shared_ffn(p, xf)
         return y.reshape(b, s, d).astype(x.dtype), aux
 
     return apply
 
 
-def _block_fwd(p, x, cfg: ModelConfig, moe_apply, window, pos_offset=0):
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    x = x + L.attention_forward(
-        p["attn"], h,
+def _attn_fwd(p, h, cfg: ModelConfig, window, pos_offset=0):
+    if cfg.kv_lora_rank:
+        return L.mla_forward(p, h, cfg, window=window, pos_offset=pos_offset)
+    return L.attention_forward(
+        p, h,
         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
         rope_theta=cfg.rope_theta, causal=True, window=window,
         pos_offset=pos_offset,
     )
+
+
+def _attn_decode(p, h, c, pos, cfg: ModelConfig):
+    if cfg.kv_lora_rank:
+        return L.mla_decode(p, h, c, pos, cfg)
+    return L.attention_decode(
+        p, h, c, pos,
+        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        rope_theta=cfg.rope_theta,
+    )
+
+
+def _block_fwd(p, x, cfg: ModelConfig, ffn_apply, window, pos_offset=0):
+    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+    x = x + _attn_fwd(p["attn"], h, cfg, window, pos_offset)
     h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    y, aux = moe_apply(p, h)
+    y, aux = ffn_apply(p, h)
     return x + y, aux
+
+
+def _dense_ffn(p, h):
+    """A leading dense layer's FFN, with the MoE apply's signature."""
+    return L.swiglu(p["mlp"], h), jnp.float32(0.0)
 
 
 def forward(
@@ -226,14 +301,17 @@ def forward(
     # +80% collective) — it fights the EP shard_map's token layout (tokens
     # sharded over data x model), inserting a reshard every layer.
 
-    def body(x, p):
-        fn = _block_fwd
-        if ctx.remat:
-            fn = jax.checkpoint(fn, static_argnums=(2, 3, 4))
-        x, aux = fn(p, x, cfg, moe_apply, window)
-        return x, aux
+    def scan_blocks(x, blocks, ffn_apply):
+        def body(x, p):
+            fn = _block_fwd
+            if ctx.remat:
+                fn = jax.checkpoint(fn, static_argnums=(2, 3, 4))
+            return fn(p, x, cfg, ffn_apply, window)
+        return jax.lax.scan(body, x, blocks)
 
-    x, auxs = jax.lax.scan(body, x, params["blocks"])
+    if cfg.first_dense_layers:
+        x, _ = scan_blocks(x, params["dense_blocks"], _dense_ffn)
+    x, auxs = scan_blocks(x, params["blocks"], moe_apply)
     if last_only:
         x = x[:, -1:]                    # §Perf B1: slice before lm_head
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -245,7 +323,14 @@ def forward(
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
                ctx: ParallelContext = SINGLE):
+    """One ring buffer per layer, dense layers first; latent attention keeps
+    each head's full k and v."""
     def one(_):
+        if cfg.kv_lora_rank:
+            return L.init_kv_cache(
+                batch, cfg.n_heads, cache_len,
+                cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                ctx.compute_dtype, v_dim=cfg.v_head_dim)
         return L.init_kv_cache(
             batch, cfg.n_kv_heads, cache_len, cfg.head_dim, ctx.compute_dtype
         )
@@ -256,20 +341,27 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig,
                 ctx: ParallelContext = SINGLE):
     x = params["embed"][token][:, None, :].astype(ctx.compute_dtype)
     moe_apply = make_moe_ffn(cfg, ctx)
+    k = cfg.first_dense_layers
 
-    def body(x, pc):
-        p, c = pc
-        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-        a, c = L.attention_decode(
-            p["attn"], h, c, pos,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta,
-        )
-        x = x + a
-        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        y, _ = moe_apply(p, h)
-        return x + y, c
+    def decode_blocks(x, blocks, cache, ffn_apply):
+        def body(x, pc):
+            p, c = pc
+            h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+            a, c = _attn_decode(p["attn"], h, c, pos, cfg)
+            x = x + a
+            h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+            y, _ = ffn_apply(p, h)
+            return x + y, c
+        return jax.lax.scan(body, x, (blocks, cache))
 
-    x, cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    if k:
+        x, c_dense = decode_blocks(x, params["dense_blocks"],
+                          jax.tree.map(lambda a: a[:k], cache), _dense_ffn)
+        x, c_moe = decode_blocks(x, params["blocks"],
+                        jax.tree.map(lambda a: a[k:], cache), moe_apply)
+        cache = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), c_dense,
+                             c_moe)
+    else:
+        x, cache = decode_blocks(x, params["blocks"], cache, moe_apply)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return (x @ params["lm_head"])[:, 0], cache

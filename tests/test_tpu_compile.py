@@ -202,3 +202,38 @@ def test_moe_one_chip_scopes(one_chip, monkeypatch):
         assert scope in found, scope
     assert any(n.endswith("nimble.ffn/jit(grouped_ffn_blocked)/grouped_ffn/"
                           "pallas_call") for n in names)
+
+
+def test_moonlight_moe_layer_and_attention(one_chip, monkeypatch):
+    """Moonlight's MoE layer (64 experts of width 1408, top-6, the sigmoid
+    router, 2 shared experts) and its latent attention, bf16 at published
+    widths over 2 x 4096 tokens: 49152 routed rows give 256-row tiles
+    (bm <= m / 2E = 384), whose VMEM is well inside the kernel's limit."""
+    from repro.kernels.grouped_ffn.ffn import VMEM_LIMIT_BYTES, _vmem_bytes
+    from repro.models import layers
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("moonlight-16b-a3b")
+    E, d, F, bf16 = cfg.n_experts, cfg.d_model, cfg.d_ff, jnp.bfloat16
+    rows = 8192 * cfg.top_k
+    bm = row_tile(rows, E, d, 128, 2, 2)
+    assert bm == 256
+    assert _vmem_bytes(bm, d, 128, 2, 2) < VMEM_LIMIT_BYTES // 4
+    s = lambda shape, t=bf16: jax.ShapeDtypeStruct(shape, t,
+                                                   sharding=one_chip)
+    Fs = cfg.n_shared_experts * F
+    p = {"router": s((d, E)), "router_bias": s((E,), jnp.float32),
+         "wg": s((E, d, F)), "wu": s((E, d, F)), "wd": s((E, F, d)),
+         "shared": {"wg": s((d, Fs)), "wu": s((d, Fs)), "wd": s((Fs, d))}}
+    apply = moe.make_moe_ffn(cfg, ParallelContext(param_dtype=bf16,
+                                                  compute_dtype=bf16))
+    text = jax.jit(lambda p, x: apply(p, x)[0]).lower(
+        p, s((2, 4096, d))).compile().as_text()
+    assert "grouped_ffn" in tpu_kernels(text)
+
+    attn = jax.tree.map(lambda a: s(a.shape),
+                        jax.eval_shape(lambda: layers.init_mla(
+                            jax.random.key(0), cfg, bf16)))
+    text = jax.jit(lambda p, x: layers.mla_forward(p, x, cfg)).lower(
+        attn, s((2, 4096, d))).compile().as_text()
+    assert "flash_attention" in tpu_kernels(text)
