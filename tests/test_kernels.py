@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.flash import flash_attention
+from repro.kernels.flash_attention.flash import (
+    block_plan, flash_attention, live_pairs,
+)
 from repro.kernels.flash_attention.ops import chunked_attention
 from repro.kernels.flash_attention.ref import mha_ref
 from repro.kernels.grouped_ffn.ffn import grouped_ffn_blocked
@@ -149,21 +151,78 @@ def test_grouped_ffn_bf16():
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.parametrize("window", [None, 96, 256])
-@pytest.mark.parametrize("B,H,Hkv,S,Dh", [
-    (2, 4, 2, 256, 64), (1, 2, 1, 128, 32), (1, 8, 8, 256, 16),
+_FLASH_SHAPES = [(2, 4, 2, 256, 64), (1, 2, 1, 128, 32), (1, 8, 8, 256, 16)]
+
+
+@pytest.mark.parametrize("B,H,Hkv,S,Dh,window,extra", [
+    *(pytest.param(*shape, window, {}, id="-".join(map(str, (*shape, window))))
+      for shape in _FLASH_SHAPES for window in (None, 96, 256)),
+    # cases that leave dead or partial block pairs, and one that leaves none
+    pytest.param(1, 2, 1, 512, 32, None, {}, id="s512-6-dead-of-16"),
+    pytest.param(1, 2, 1, 512, 32, None, {"bq": 256}, id="bq256-bk128"),
+    pytest.param(1, 2, 1, 256, 48, None, {"dv": 32}, id="dh48-dv32"),
+    pytest.param(1, 2, 1, 256, 32, None, {"sk": 512, "q_offset": 256},
+                 id="sq256-sk512-q_offset256"),
+    pytest.param(1, 2, 1, 512, 32, 128, {}, id="window-kills-below"),
+    pytest.param(1, 2, 1, 256, 32, None, {"causal": False}, id="non-causal"),
 ])
-def test_flash_vs_ref(B, H, Hkv, S, Dh, window):
+def test_flash_vs_ref(B, H, Hkv, S, Dh, window, extra):
+    Sk, Dv = extra.get("sk", S), extra.get("dv", Dh)
+    causal, q_offset = extra.get("causal", True), extra.get("q_offset", 0)
     q = (RNG.normal(size=(B, H, S, Dh)) * 0.3).astype(np.float32)
-    k = (RNG.normal(size=(B, Hkv, S, Dh)) * 0.3).astype(np.float32)
-    v = (RNG.normal(size=(B, Hkv, S, Dh)) * 0.3).astype(np.float32)
+    k = (RNG.normal(size=(B, Hkv, Sk, Dh)) * 0.3).astype(np.float32)
+    v = (RNG.normal(size=(B, Hkv, Sk, Dv)) * 0.3).astype(np.float32)
     o = flash_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                        causal=True, window=window, bq=128, bk=128,
-                        interpret=True)
+                        causal=causal, window=window, q_offset=q_offset,
+                        bq=extra.get("bq", 128), bk=128, interpret=True)
     r = mha_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                causal=True, window=window)
+                causal=causal, window=window, q_offset=q_offset)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=2e-5, atol=2e-6)
+
+
+def test_block_plan_moonlight_cell():
+    """S 4096 in 128 x 128 blocks, causal: 528 pairs visited per (batch,
+    head), the 32 on the diagonal masked, 496 left out of the grid."""
+    assert block_plan(4096, 4096, 128, 128, True, None, 0) == (528, 32, 496)
+
+
+@pytest.mark.parametrize("sq,sk", [(512, 512), (4096, 4096), (256, 1024)])
+def test_block_plan_non_causal_keeps_full_grid(sq, sk):
+    n = (sq // 128) * (sk // 128)
+    assert block_plan(sq, sk, 128, 128, False, None, 0) == (n, 0, 0)
+    assert list(zip(*live_pairs(sq, sk, 128, 128, False, None, 0))) == [
+        (i, j) for i in range(sq // 128) for j in range(sk // 128)]
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,window,q_offset", [
+    (512, 512, 128, 128, True, 128, 0),
+    (512, 512, 128, 64, True, 200, 0),
+    (1024, 1024, 256, 128, True, 300, 0),
+    (256, 512, 128, 128, True, None, 256),
+    (128, 512, 64, 128, True, 100, 384),
+    (512, 512, 128, 128, False, 256, 0),
+])
+def test_block_plan_matches_brute_force(sq, sk, bq, bk, causal, window,
+                                        q_offset):
+    """Pairs kept and masked agree with the mask evaluated at every
+    (query, key) position; steps run row by row, kv blocks in order."""
+    qpos = np.arange(sq)[:, None] + q_offset
+    kpos = np.arange(sk)[None, :]
+    keep = np.ones((sq, sk), bool)
+    if causal:
+        keep &= kpos <= qpos
+    if window is not None:
+        keep &= kpos > qpos - window
+    blocks = keep.reshape(sq // bq, bq, sk // bk, bk).transpose(0, 2, 1, 3)
+    live = blocks.any(axis=(2, 3))
+    partial = live & ~blocks.all(axis=(2, 3))
+    assert block_plan(sq, sk, bq, bk, causal, window, q_offset) == (
+        live.sum(), partial.sum(), (~live).sum())
+    q_block, kv_block = live_pairs(sq, sk, bq, bk, causal, window, q_offset)
+    rows, cols = np.nonzero(live)
+    np.testing.assert_array_equal(q_block, rows)
+    np.testing.assert_array_equal(kv_block, cols)
 
 
 @pytest.mark.parametrize("window", [None, 100])

@@ -98,6 +98,22 @@ def test_flash_attention_granite_widths(one_chip):
     assert "flash_attention" in tpu_kernels(compiled.as_text())
 
 
+def test_flash_attention_moonlight_widths(one_chip):
+    """Latent attention's widths in bf16 (keys 192 wide, values 128), causal
+    over 2 x 4096 positions: the grid of live block pairs and its two
+    scalar-prefetched tables compile for the chip."""
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                           sharding=one_chip)
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    compiled = jax.jit(fn).lower(
+        s((2, 16, 4096, 192)), s((2, 16, 4096, 192)),
+        s((2, 16, 4096, 128))).compile()
+    assert "flash_attention" in tpu_kernels(compiled.as_text())
+
+
 @pytest.mark.parametrize("batch", [1, 4])
 def test_flash_attention_on_four_chip_mesh(topo, monkeypatch, batch):
     """Under a (data=1, model=4) mesh the kernel runs per shard, split over
