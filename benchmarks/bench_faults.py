@@ -21,9 +21,9 @@ measuring what graceful degradation actually bought:
     dropout composed on one run: the loop survives, straggler inflation is
     visible in the reports, and no telemetry record is rejected.
 
-Metrics land in ``BENCH_faults.json`` (tagged ``nimble.bench_faults/v1``)
-for ``experiments/make_report.py``; :func:`validate_faults` is the
-``run.py --smoke`` gate (schema + recovery/availability thresholds).
+Metrics land in ``BENCH_faults.json`` (tagged ``nimble.bench_faults/v1``);
+:func:`validate_faults` is the ``run.py --smoke`` gate (schema +
+recovery/availability thresholds).
 """
 
 from __future__ import annotations

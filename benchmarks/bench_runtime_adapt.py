@@ -19,11 +19,10 @@ Scenarios mirror the runtime acceptance criteria:
     demand served off the dead link.
 
 Metrics land in ``BENCH_runtime_adapt.json`` (tagged
-``nimble.bench_runtime_adapt/v1``) for the per-PR bench trajectory and
-``experiments/make_report.py``.  All three policies run through
-:class:`repro.api.Session` (DESIGN.md §5): static vs adaptive is a
-one-field ``SessionSpec`` diff, and the oracle is the session's
-``run_oracle`` bookend.
+``nimble.bench_runtime_adapt/v1``) for the per-PR bench trajectory.  All
+three policies run through :class:`repro.api.Session` (DESIGN.md §5):
+static vs adaptive is a one-field ``SessionSpec`` diff, and the oracle is
+the session's ``run_oracle`` bookend.
 """
 
 from __future__ import annotations

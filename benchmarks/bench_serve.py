@@ -23,9 +23,8 @@ scenario's declared :class:`~repro.serve.SloSpec` gates the pair:
     than 2% over the whole horizon.
 
 Metrics land in ``BENCH_serve.json`` (tagged ``nimble.serve/v1``, the
-adaptive arm's full per-scenario reports embedded) for
-``experiments/make_report.py``; :func:`validate_serve` is the ``run.py
---smoke`` ``serve_slo`` gate.
+adaptive arm's full per-scenario reports embedded);
+:func:`validate_serve` is the ``run.py --smoke`` ``serve_slo`` gate.
 """
 
 from __future__ import annotations

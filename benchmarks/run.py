@@ -14,7 +14,6 @@ writes the aggregate to benchmarks/results.csv.
   (faults)    bench_faults          fault drills: flap/blackout/crash recovery
   (serve)     bench_serve           serving control plane: scenario SLO drills
   (lint)      bench_lint            static invariant checker verdict
-  (extra)     bench_kernels         kernel micro-benches
 
 ``--smoke`` runs the planner-overhead, runtime-adaptation, fairness,
 fault-drill, and serving-control-plane sections in a few seconds and
@@ -285,7 +284,6 @@ def main() -> None:
         bench_alltoallv_skew,
         bench_fairness,
         bench_faults,
-        bench_kernels,
         bench_lint,
         bench_moe_e2e,
         bench_multitenant,
@@ -312,7 +310,6 @@ def main() -> None:
         ("serve", bench_serve),
         ("obs", bench_obs),
         ("lint", bench_lint),
-        ("kernels", bench_kernels),
     ]
     metric_files = {
         "runtime_adapt": ("BENCH_runtime_adapt.json", "bench_runtime_adapt"),

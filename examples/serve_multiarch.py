@@ -3,8 +3,7 @@
 Drives the ServeEngine (prefill + autoregressive decode with per-family
 caches: KV ring buffers, Mamba/xLSTM recurrent states, whisper cross-attn)
 for one reduced model per family, with batched requests and greedy +
-temperature sampling.  Demonstrates the serving substrate the decode input
-shapes (decode_32k / long_500k) lower in the dry-run.
+temperature sampling.
 
 Run:
     PYTHONPATH=src python examples/serve_multiarch.py

@@ -363,10 +363,10 @@ class Session:
         runtime_stats/v1``, ``nimble.telemetry_aggregate/v1``,
         ``nimble.runtime_trace/v1`` (last ``run_trace``), ``nimble.
         fabric_fairness/v1`` and ``nimble.fabric_arbiter_stats/v1`` — so
-        existing consumers (``experiments/make_report.py``, the benches)
-        dispatch on the kinds they already know — plus a
-        ``nimble.metrics/v1`` snapshot (DESIGN.md §11) collected from the
-        live stack, whether or not a recorder is attached.
+        existing consumers (the benches) dispatch on the kinds they already
+        know — plus a ``nimble.metrics/v1`` snapshot (DESIGN.md §11)
+        collected from the live stack, whether or not a recorder is
+        attached.
         """
         self._require_active()
         payload: dict = {

@@ -18,9 +18,8 @@ register(ModelConfig(
     ssm_state=64,
     ssm_heads=4,
     slstm_every=2,             # even layers sLSTM, odd mLSTM
-    # §Perf A1/A2 (EXPERIMENTS.md): chunkwise-parallel mLSTM + associative-
-    # scan sLSTM — 208x lower memory roofline term vs the per-step scan
-    # baseline (selectable back via mlstm_chunk=0 / slstm_assoc=False).
+    # chunkwise-parallel mLSTM + associative-scan sLSTM (the per-step scans
+    # stay selectable via mlstm_chunk=0 / slstm_assoc=False)
     mlstm_chunk=64,
     slstm_assoc=True,
 ))

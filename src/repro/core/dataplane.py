@@ -111,8 +111,8 @@ class NimbleAllToAll:
         else:
             self.topo = Topology(n_devices, group_size)
         # direct (NCCL/PXN-like) routes everything on k=0, so it provisions
-        # no alternate slots — otherwise the dry-run would charge the static
-        # baseline NIMBLE's wire padding (EXPERIMENTS.md §Perf fairness note)
+        # no alternate slots: slots it never fills would still be moved every
+        # round, charging the static baseline NIMBLE's wire padding
         if mode == "direct":
             alt_frac = 0.0
         self.sched: CommSchedule = build_schedule(self.topo, max_chunks, alt_frac)

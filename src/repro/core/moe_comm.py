@@ -6,7 +6,7 @@ The paper's headline workload: MoE token routing is a skewed All-to-Allv
 
   1. tokens are assigned to experts (top-k gating, done by the model);
   2. assignments are packed into per-destination-device chunk buffers
-     ("Kernel Scatter", Pallas ``token_scatter`` on TPU, jnp fallback here);
+     ("Kernel Scatter": a stable sort by destination and a jnp scatter);
   3. the live demand matrix is planned + executed by
      :class:`~repro.core.dataplane.NimbleAllToAll` — tokens ride a bf16/f32
      payload, the per-token expert id rides a tiny f32 sideband on the SAME

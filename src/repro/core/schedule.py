@@ -123,7 +123,7 @@ class CommSchedule:
     ``C`` chunk slots are provisioned per destination on the direct path
     (k=0) — enough for the whole demand as fallback — and
     ``ceil(C * alt_frac)`` on each alternate, trading wire padding for
-    rerouting headroom (tunable; see EXPERIMENTS.md §Perf).
+    rerouting headroom (tunable).
     """
 
     topo: Topology

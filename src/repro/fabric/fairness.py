@@ -12,8 +12,8 @@ demand, so scaling by the weight normalizes entitlement away):
     weighted max-min fair; 1 when some tenant is fully crowded out.
 
 Reports are emitted through the shared ``repro.jsonio`` schema
-(``nimble.fabric_fairness/v1``) so benches and ``experiments/make_report``
-consume them like any other record.
+(``nimble.fabric_fairness/v1``) so benches consume them like any other
+record.
 """
 
 from __future__ import annotations

@@ -1,15 +1,15 @@
 """JSON bytes IO with an orjson fast path and a stdlib fallback.
 
 The container may not ship ``orjson``; all writers (checkpoint index,
-dry-run records, fabric-sim results, runtime telemetry, benches) use these
-helpers so the fallback lives in one place and the on-disk format stays
-identical either way.
+fabric-sim results, runtime telemetry, benches) use these helpers so the
+fallback lives in one place and the on-disk format stays identical either
+way.
 
 Records that cross files (fabsim results, telemetry windows, runtime trace
 summaries, bench outputs) share one envelope: :func:`tag` stamps a
 ``schema`` field of the form ``nimble.<kind>/v<version>`` so
-``experiments/make_report.py`` and the benches can consume each other's
-output without per-file format knowledge.
+the benches can consume each other's output without per-file format
+knowledge.
 """
 
 from __future__ import annotations

@@ -1,16 +1,13 @@
-"""Public attention op with three execution paths:
+"""Public attention op: the Pallas flash kernel (``flash.py``) for queries of
+128 rows or more, in interpret mode off the TPU (``resolve_interpret``).
 
-  * **TPU**: the Pallas flash kernel (``flash.py``) — the target artifact;
-  * **non-TPU, long sequences**: ``chunked_attention`` — the same online-
-    softmax algorithm expressed as a pure-jnp ``lax.scan`` over kv blocks.
-    This is what dry-run lowering uses: identical FLOPs and O(S) memory,
-    so the roofline derived from the compiled HLO is faithful, while
-    compile size stays constant in sequence length;
-  * **small shapes**: the quadratic reference (cheapest to compile/run).
+Shorter queries (decode) take ``chunked_attention``, the same online-softmax
+algorithm as a ``lax.scan`` over kv chunks, when the cache is longer than two
+chunks, and the quadratic reference otherwise.  The split is chosen from the
+shapes alone, the same on every backend.
 
-Gradients: jnp paths differentiate natively (scan AD = recompute-based,
-flash-like memory).  The Pallas path uses a reference VJP (a backward
-Pallas kernel is a TPU-only optimization, noted in EXPERIMENTS.md).
+Gradients: the flash path's VJP differentiates ``chunked_attention``; the
+jnp paths differentiate natively.
 """
 
 from __future__ import annotations
@@ -64,10 +61,6 @@ def chunked_attention(
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(-1)
-        # NOTE (§Perf C3, refuted): storing probs as bf16 for bf16 inputs
-        # (flash-kernel style) MEASURED +2.2% memory on the MoE dry-run —
-        # XLA:CPU legalizes bf16 compute to f32, so the cast only inserts
-        # converts.  The Pallas TPU kernel does keep bf16 P·V natively.
         acc = acc * alpha[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, vb)
         return (m_new, l, acc, ci + 1), None
 
@@ -79,13 +72,12 @@ def chunked_attention(
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attention_tpu(q, k, v, causal, window, q_offset):
-    return _flash(q, k, v, causal=causal, window=window, q_offset=q_offset,
-                  interpret=False)
+def _attention_flash(q, k, v, causal, window, q_offset):
+    return _flash(q, k, v, causal=causal, window=window, q_offset=q_offset)
 
 
 def _fwd(q, k, v, causal, window, q_offset):
-    return _attention_tpu(q, k, v, causal, window, q_offset), (q, k, v)
+    return _attention_flash(q, k, v, causal, window, q_offset), (q, k, v)
 
 
 def _bwd(causal, window, q_offset, res, g):
@@ -99,7 +91,7 @@ def _bwd(causal, window, q_offset, res, g):
     return vjp(g)
 
 
-_attention_tpu.defvjp(_fwd, _bwd)
+_attention_flash.defvjp(_fwd, _bwd)
 
 
 def _on_mesh(q, k, v, causal, window, q_offset):
@@ -114,7 +106,7 @@ def _on_mesh(q, k, v, causal, window, q_offset):
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
-        return _attention_tpu(q, k, v, causal, window, q_offset)
+        return _attention_flash(q, k, v, causal, window, q_offset)
     b_axes, h_axes = [], []
     b_div, h_div = q.shape[0], k.shape[1]
     for name, size in mesh.shape.items():
@@ -133,7 +125,7 @@ def _on_mesh(q, k, v, causal, window, q_offset):
                 f"({k.shape[1]}) left to split")
     spec = P(tuple(b_axes) or None, tuple(h_axes) or None, None, None)
     return jax.shard_map(
-        lambda q, k, v: _attention_tpu(q, k, v, causal, window, q_offset),
+        lambda q, k, v: _attention_flash(q, k, v, causal, window, q_offset),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
     )(q, k, v)
 
@@ -141,14 +133,9 @@ def _on_mesh(q, k, v, causal, window, q_offset):
 def attention(q, k, v, causal=True, window=None, q_offset=0):
     """[B,H,Sq,Dh] x [B,Hkv,Sk,Dh] x [B,Hkv,Sk,Dv] -> [B,H,Sq,Dv]; GQA via
     Hkv | H."""
-    sk = k.shape[2]
-    if jax.default_backend() == "tpu" and q.shape[2] >= 128:
+    if q.shape[2] >= 128:
         return _on_mesh(q, k, v, causal, window, q_offset)
-    # NOTE (§Perf B2, refuted): routing medium sequences (256 < Sk <= 2k)
-    # through chunked_attention was MEASURED WORSE (+7% memory term on
-    # whisper prefill) — the per-chunk accumulator rescale traffic exceeds
-    # the saved probs passes at small Sk.  Threshold kept at 2*_CHUNK.
-    if sk > 2 * _CHUNK:
+    if k.shape[2] > 2 * _CHUNK:
         return chunked_attention(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset)
     return mha_ref(q, k, v, causal=causal, window=window, q_offset=q_offset)

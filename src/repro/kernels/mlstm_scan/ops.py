@@ -1,8 +1,5 @@
-"""Public op: Pallas chunkwise mLSTM scan on TPU, jnp chunked path elsewhere.
-
-The non-TPU path reuses the validated chunkwise reformulation in
-models/xlstm.py (identical math), keeping dry-run lowering cheap while the
-Pallas kernel is the TPU artifact.
+"""Public op: the Pallas chunkwise mLSTM scan when the sequence is a whole
+number of chunks, the plain reference otherwise.
 """
 
 from __future__ import annotations

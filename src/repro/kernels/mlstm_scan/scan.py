@@ -1,11 +1,8 @@
 """Pallas TPU kernel: chunkwise-parallel mLSTM scan.
 
-The EXPERIMENTS.md §Perf PAIR-A analysis identified the mLSTM matrix-memory
-round-trip as the xlstm memory-term floor; the chunked jnp reformulation
-(models/xlstm.py) cut it 10.2x, and this kernel is the TPU artifact that
-takes the remaining step: the carried (C, n, m) state lives in VMEM scratch
-across the sequential chunk dimension, so HBM sees only q/k/v/gate inputs
-and the h output — one pass each way.
+The carried (C, n, m) state of the chunked reformulation (models/xlstm.py)
+lives in VMEM scratch across the sequential chunk dimension, so HBM sees
+only q/k/v/gate inputs and the h output — one pass each way.
 
 Grid = (batch, heads, chunks); chunks innermost/sequential.  Per step the
 kernel computes the exact stabilized chunk recurrence of

@@ -1,25 +1,17 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-Single pod: (data=16, model=16) = 256 chips.  Multi-pod: (pod=2, data=16,
-model=16) = 512 chips; the pod axis is pure data parallel (gradient
-all-reduce over DCI), the model axis hosts tensor/expert parallelism and is
-the NIMBLE orchestration axis (DESIGN.md §8).
+The "model" axis hosts tensor/expert parallelism and is the NIMBLE
+orchestration axis; "data" (and "pod", where a mesh has one) is pure data
+parallel (DESIGN.md §8).
 
 A FUNCTION, not a module constant: importing this module never touches jax
-device state (the dry-run must set XLA_FLAGS before first jax init).
+device state (a process may still set XLA_FLAGS, e.g. a forced host device
+count, before its first jax call).
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    import jax.sharding as jsh
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jsh.AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_devices: int | None = None, model: int | None = None):

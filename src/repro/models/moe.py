@@ -145,12 +145,13 @@ def _moe_local(p, xf, top_idx, top_w, cfg: ModelConfig):
     return y
 
 
-def _moe_ep(p, xf, top_idx, top_w, cfg: ModelConfig, ctx: ParallelContext,
-            dispatcher: MoEDispatcher):
-    """Expert-parallel path (inside shard_map): NIMBLE dispatch/combine."""
-    epd = cfg.n_experts // ctx.ep_size
+def _moe_ep(p, xf, top_idx, top_w, cfg: ModelConfig,
+            dispatcher: MoEDispatcher, token_valid=None):
+    """Expert-parallel path (inside shard_map): NIMBLE dispatch/combine.
+    ``token_valid`` marks the tokens this device owns (None: all)."""
     with jax.named_scope(DISPATCH):
-        recv, e_local, state = dispatcher.dispatch(xf, top_idx)
+        recv, e_local, state = dispatcher.dispatch(xf, top_idx,
+                                                   token_valid=token_valid)
     n, C, ct, d = recv.shape
     with jax.named_scope(FFN):
         flat = recv.reshape(n * C * ct, d)
@@ -202,7 +203,7 @@ def make_moe_ffn(cfg: ModelConfig, ctx: ParallelContext):
 
     def _inner_full(wg, wu, wd, xf, ti, tw):
         pp = {"wg": wg, "wu": wu, "wd": wd}
-        return _moe_ep(pp, xf, ti, tw, cfg, ctx, dispatcher)
+        return _moe_ep(pp, xf, ti, tw, cfg, dispatcher)
 
     def _inner_masked(wg, wu, wd, xf, ti, tw):
         """Tokens replicated over the model axis (small decode batches):
@@ -211,19 +212,8 @@ def make_moe_ffn(cfg: ModelConfig, ctx: ParallelContext):
         (DESIGN.md §8)."""
         pp = {"wg": wg, "wu": wu, "wd": wd}
         me = jax.lax.axis_index(ctx.model_axis)
-        T = xf.shape[0]
-        owned = (jnp.arange(T) % ctx.ep_size) == me
-        with jax.named_scope(DISPATCH):
-            recv, e_local, state = dispatcher.dispatch(xf, ti,
-                                                       token_valid=owned)
-        n, C, ct, d = recv.shape
-        with jax.named_scope(FFN):
-            y = grouped_ffn(
-                recv.reshape(n * C * ct, d), e_local.reshape(n * C * ct),
-                pp["wg"], pp["wu"], pp["wd"],
-                block_tokens=64, block_ffn=min(128, cfg.d_ff),
-            )
-        out = dispatcher.combine(y.reshape(n, C, ct, d), state, tw)
+        owned = (jnp.arange(xf.shape[0]) % ctx.ep_size) == me
+        out = _moe_ep(pp, xf, ti, tw, cfg, dispatcher, token_valid=owned)
         return jax.lax.psum(out, ctx.model_axis)
 
     def apply(p, x):
@@ -296,10 +286,9 @@ def forward(
     """tokens [B, S] -> (logits [B, S, V], aux_loss scalar)."""
     x = params["embed"][tokens].astype(ctx.compute_dtype)
     moe_apply = make_moe_ffn(cfg, ctx)
-    # NOTE (§Perf D, refuted for MoE): pinning batch to the data axes here
-    # (as dense.forward does) MEASURED worse on qwen3-moe (+9.5% memory,
-    # +80% collective) — it fights the EP shard_map's token layout (tokens
-    # sharded over data x model), inserting a reshard every layer.
+    # no constrain_tokens here (dense.forward has one): the EP shard_map
+    # shards tokens over data x model, and pinning the batch to the data
+    # axes alone would reshard at every layer
 
     def scan_blocks(x, blocks, ffn_apply):
         def body(x, p):

@@ -2,8 +2,8 @@
 
 ``build_model(cfg, ctx)`` returns a :class:`Model` exposing
 ``init / forward / loss / init_cache / decode_step / input_specs`` with the
-same signatures across all 10 assigned architectures, so the launcher,
-dry-run, and benchmarks are arch-agnostic.
+same signatures across all the architectures, so the launchers and
+benchmarks are arch-agnostic.
 """
 
 from __future__ import annotations
@@ -86,7 +86,7 @@ class Model:
         return self.mod.decode_step(params, cache, token, pos, self.cfg,
                                     self.ctx)
 
-    # -- dry-run input specs ------------------------------------------------------
+    # -- input specs --------------------------------------------------------------
     def supports(self, shape: InputShape) -> bool:
         return shape.name not in self.cfg.skip_shapes
 

@@ -256,7 +256,7 @@ def linear_prefix(a, u):
     """First-order linear recurrence with a hand-written adjoint.
 
     Differentiating *through* ``associative_scan`` emits per-level pad/slice
-    traffic (~35% of the memory term in the dry-run profile).  The adjoint
+    traffic.  The adjoint
     of y_t = a_t y_{t-1} + u_t is itself a REVERSE linear recurrence
         c̄_t = ȳ_t + a_{t+1} c̄_{t+1},   ā_t = c̄_t y_{t-1},   ū_t = c̄_t,
     so backward is one more associative_scan instead of an unrolled

@@ -1,10 +1,9 @@
 """Batched serving engine: prefill + greedy/temperature decode loop.
 
-``make_serve_step`` builds the single-token decode function the dry-run
-lowers for the decode input shapes (one new token against a seq_len-deep
-cache).  ``make_prefill_scan`` rolls the per-token prompt prefill into one
-``lax.scan`` — a single jitted dispatch instead of P host round-trips,
-bit-identical to stepping the prompt token by token (pinned by
+``make_serve_step`` builds the single-token decode function (one new token
+against a seq_len-deep cache).  ``make_prefill_scan`` rolls the per-token
+prompt prefill into one ``lax.scan`` — a single jitted dispatch instead of
+P host round-trips, bit-identical to stepping the prompt token by token (pinned by
 ``tests/test_serve_engine.py``).  ``ServeEngine`` drives both for real
 batched requests (examples/ and the end-to-end serving smoke test).
 """

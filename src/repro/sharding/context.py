@@ -50,8 +50,8 @@ def constrain_tokens(x, ctx: "ParallelContext"):
 
     XLA's sharding propagation sometimes trades the batch sharding away to
     shard attention heads instead — replicating the FULL global batch per
-    device (observed on zamba2's shared-attention block: 768 GB/device
-    peak, EXPERIMENTS.md §Perf PAIR D).  A no-op without a mesh.
+    device (observed on zamba2's shared-attention block).  A no-op without
+    a mesh.
     """
     if ctx.mesh is None:
         return x

@@ -13,13 +13,11 @@ paper leaves to stock ring/tree (§IV-E).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import InputShape
 from repro.sharding.context import ParallelContext
 
 # leaf-name -> spec template (rightmost dims; missing leading dims -> None)
@@ -123,81 +121,3 @@ def build_param_shardings(params, ctx: ParallelContext):
     specs = build_param_specs(params, ctx)
     return jax.tree.map(lambda s: NamedSharding(ctx.mesh, s), specs)
 
-
-# --------------------------------------------------------------------------- #
-# batch / cache specs
-# --------------------------------------------------------------------------- #
-
-
-def batch_axes(ctx: ParallelContext) -> Tuple[str, ...]:
-    """Axes that shard the batch dim (pod + data)."""
-    return tuple(a for a in ctx.data_axes)
-
-
-def batch_spec(ctx: ParallelContext, global_batch: int) -> P:
-    axes = []
-    remaining = global_batch
-    for a in batch_axes(ctx):
-        sz = _axis_size(ctx, a)
-        if remaining % sz == 0 and sz > 1:
-            axes.append(a)
-            remaining //= sz
-    if not axes:
-        return P(None)
-    return P(tuple(axes))
-
-
-def input_specs_sharding(model_inputs, ctx: ParallelContext,
-                         shape: InputShape):
-    """NamedShardings for a dict of ShapeDtypeStructs (dry-run inputs)."""
-    bspec = batch_spec(ctx, shape.global_batch)
-
-    def one(name, s):
-        if s.ndim == 0:
-            return NamedSharding(ctx.mesh, P())
-        parts = [bspec[0] if bspec != P(None) else None]
-        parts += [None] * (s.ndim - 1)
-        # modality stubs: shard embedding dim over model
-        if name in ("frames", "patches") and s.ndim == 3:
-            parts[-1] = "model" if _axis_size(ctx, "model") <= s.shape[-1] else None
-        return NamedSharding(ctx.mesh, P(*parts))
-
-    return {k: one(k, v) for k, v in model_inputs.items()}
-
-
-def cache_spec_rules(ctx: ParallelContext):
-    """KV / state caches: heads (or inner channels) over model, batch over data."""
-    def spec(path, leaf):
-        shape = leaf.shape
-        names = [str(getattr(k, "key", getattr(k, "idx", ""))) for k in path]
-        leaf_name = names[-1] if names else ""
-        if leaf_name in ("k", "v") and len(shape) >= 4:
-            # [L, B, Hkv, S, dh] or [B, Hkv, S, dh]
-            parts = [None] * len(shape)
-            if shape[-4] % _axis_size(ctx, "data") == 0:
-                parts[-4] = "data"
-            m = _axis_size(ctx, "model")
-            if shape[-3] % m == 0:
-                parts[-3] = "model"          # shard KV heads (GQA permitting)
-            elif shape[-2] % m == 0:
-                parts[-2] = "model"          # else sequence-shard the cache
-            return P(*parts)
-        if leaf_name in ("C", "n", "ssm", "conv") and len(shape) >= 2:
-            parts = [None] * len(shape)
-            # batch dim position: [L?, B, ...] — find the first dim >= data size
-            ds = _axis_size(ctx, "data")
-            for i, d in enumerate(shape):
-                if ds > 1 and d % ds == 0 and d >= ds:
-                    parts[i] = "data"
-                    break
-            # shard the channel dim over model if divisible
-            ms = _axis_size(ctx, "model")
-            if parts[-1] is None and shape[-1] % ms == 0 and shape[-1] >= ms:
-                parts[-1] = "model"
-            return P(*parts)
-        return P()
-    return spec
-
-
-def build_cache_specs(cache, ctx: ParallelContext):
-    return jax.tree_util.tree_map_with_path(cache_spec_rules(ctx), cache)

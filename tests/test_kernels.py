@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,12 +10,10 @@ import pytest
 from repro.kernels.flash_attention.flash import (
     block_plan, flash_attention, live_pairs,
 )
-from repro.kernels.flash_attention.ops import chunked_attention
+from repro.kernels.flash_attention.ops import attention, chunked_attention
 from repro.kernels.flash_attention.ref import mha_ref
 from repro.kernels.grouped_ffn.ffn import grouped_ffn_blocked
-from repro.kernels.grouped_ffn.ops import (
-    _grouped_ffn, grouped_ffn, grouped_ffn_scan, tile_plan,
-)
+from repro.kernels.grouped_ffn.ops import grouped_ffn, tile_plan
 from repro.kernels.grouped_ffn.ref import grouped_ffn_ref
 from repro.kernels.relay_copy.relay import relay_copy
 from repro.kernels.token_scatter.ops import token_gather
@@ -85,6 +85,10 @@ def _routing(kind, N, E):
         return np.full((N,), E - 1)
     if kind == "all_invalid":
         return np.full((N,), -1)
+    if kind == "eighth_invalid":    # every row routed but the first eighth
+        eid = RNG.integers(0, E, size=(N,))
+        eid[: N // 8] = -1
+        return eid
     if kind == "ragged":            # counts off the 128-row sub-tile
         eid = np.full((N,), -1)
         eid[:300], eid[300:301], eid[301:901] = 0, E - 1, 1
@@ -101,13 +105,16 @@ def _routing(kind, N, E):
     (1536, 16, 32, 3, 32, 32, "ragged"),
     (96, 32, 64, 2, 16, 32, "all_invalid"),
     (2048, 16, 32, 2, 64, 32, "random"),
+    (256, 16, 32, 8, 16, 32, "eighth_invalid"),
+    (130, 8, 8, 4, 16, 8, "eighth_invalid"),
+    (700, 32, 64, 4, 64, 64, "random"),
 ])
 def test_grouped_ffn_pallas(N, D, F, E, bt, bf, routing):
-    """The kernel's path (row tile, sub-tile and empty-tile skipping) in
-    interpret mode, whatever size the CPU dispatch would send elsewhere."""
+    """The public op (row tile, sub-tile and empty-tile skipping) runs the
+    kernel in interpret mode at every size."""
     x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
     eid = jnp.asarray(_routing(routing, N, E), jnp.int32)
-    y = _grouped_ffn(x, eid, wg, wu, wd, bt, bf)
+    y = grouped_ffn(x, eid, wg, wu, wd, block_tokens=bt, block_ffn=bf)
     ref = grouped_ffn_ref(x, eid, wg, wu, wd)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
@@ -127,12 +134,23 @@ def test_tile_plan():
     assert decode.bm == 64 and decode.tiles == 2 and decode.rows == 128
 
 
-def test_grouped_ffn_scan_matches_ref():
-    x, eid, wg, wu, wd = _ffn_inputs(700, 32, 64, 4)
-    y = grouped_ffn_scan(x, eid, wg, wu, wd, block_tokens=64)
-    ref = grouped_ffn_ref(x, eid, wg, wu, wd)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
-                               rtol=2e-5, atol=2e-6)
+def test_grouped_ffn_grad_matches_ref():
+    """Gradients through the public op's VJP equal the reference's."""
+    N, D, F, E = 96, 8, 8, 4
+    x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
+    eid = jnp.asarray(RNG.integers(0, E, size=(N,)), jnp.int32)
+
+    def loss(fn, x, wg, wu, wd):
+        return jnp.sum(fn(x, eid, wg, wu, wd) ** 2)
+
+    op = functools.partial(grouped_ffn, block_tokens=16, block_ffn=8)
+    grad = functools.partial(jax.grad, argnums=(1, 2, 3, 4))
+    g = grad(loss)(op, x, wg, wu, wd)
+    gr = grad(loss)(grouped_ffn_ref, x, wg, wu, wd)
+    for a, b in zip(g, gr):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=1e-4)
 
 
 def test_grouped_ffn_bf16():
@@ -251,6 +269,40 @@ def test_flash_decode_offset():
                 causal=True, q_offset=64)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("H,Hkv,Sq,Sk,window,q_offset,grad", [
+    pytest.param(4, 2, 256, 256, None, 0, False, id="causal-gqa-s256"),
+    pytest.param(4, 2, 256, 256, 96, 0, False, id="window96"),
+    pytest.param(2, 1, 256, 512, None, 256, False, id="sq256-sk512-offset"),
+    pytest.param(2, 2, 64, 256, None, 192, False, id="sq64-mha-ref"),
+    pytest.param(2, 1, 8, 4608, None, 4600, False, id="sq8-long-cache"),
+    pytest.param(4, 2, 256, 256, None, 0, True, id="grad-causal-gqa"),
+    pytest.param(2, 1, 256, 256, 96, 0, True, id="grad-window96"),
+])
+def test_attention_op_matches_ref(H, Hkv, Sq, Sk, window, q_offset, grad):
+    """The public op on the CPU: queries of 128 rows or more take the flash
+    kernel (interpret mode) and its VJP, shorter ones the chunked path
+    (cache over two chunks) or the reference."""
+    Dh = 32
+    q, k, v = (jnp.asarray((RNG.normal(size=(1, h, s, Dh)) * 0.3)
+                           .astype(np.float32))
+               for h, s in ((H, Sq), (Hkv, Sk), (Hkv, Sk)))
+    op = functools.partial(attention, causal=True, window=window,
+                           q_offset=q_offset)
+    ref = functools.partial(mha_ref, causal=True, window=window,
+                            q_offset=q_offset)
+    if not grad:
+        np.testing.assert_allclose(np.asarray(op(q, k, v)),
+                                   np.asarray(ref(q, k, v)),
+                                   rtol=2e-5, atol=2e-6)
+        return
+    w = jnp.asarray(RNG.normal(size=(1, H, Sq, Dh)).astype(np.float32))
+    g = jax.grad(lambda *a: jnp.sum(op(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=1e-5)
 
 
 # --------------------------------------------------------------------------- #

@@ -90,15 +90,14 @@ def test_benchmark_reference_is_a_copy():
             == (ROOT / "src/repro/models/moonlight_ref.py").read_text())
 
 
-@pytest.mark.parametrize("path,seq", [("kernel", 16), ("scan", 256),
-                                      ("dense", 256)])
-def test_forward_matches_reference(path, seq, monkeypatch):
-    """``kernel``: 96 routed rows take the Pallas grouped FFN in interpret
-    mode; ``scan`` and ``dense``: 1536 rows take the CPU paths.  Both sides
-    are float32 at "highest" and differ only in summation order, ~1e-6 of
-    the largest logit; 1e-4 leaves room (a wrong gate moves it ~1e-1)."""
-    monkeypatch.setenv("NIMBLE_FFN_IMPL", "scan" if path == "scan"
-                       else "dense")
+@pytest.mark.parametrize("seq", [16, 128, 256])
+def test_forward_matches_reference(seq):
+    """The chip's kernels in interpret mode: at 16 positions attention takes
+    the reference and each expert one row tile; at 128 one partial flash
+    block; at 256 the flash grid skips its one dead block pair and the 1536
+    routed rows give experts several tiles.  Both sides are float32 at
+    "highest" and differ only in summation order, ~1e-6 of the largest
+    logit; 1e-4 leaves room (a wrong gate moves it ~1e-1)."""
     params, toks = make_params(), tokens(2, seq)
     with jax.default_matmul_precision("highest"):
         y, aux = jax.jit(build_model(CFG, SINGLE).forward)(
@@ -187,14 +186,14 @@ def test_decode_matches_forward():
     assert rel_gap(jnp.stack(outs, 1), full) < 1e-4
 
 
-def _ep_gap() -> float:
+def _ep_gap(batch, seq) -> float:
     """EP=4 over (data=1, model=4), NIMBLE mode, capacity factor 4 (no
     drops): the largest gap of its logits from the one-device forward."""
     from repro.launch.mesh import make_test_mesh
     from repro.sharding.specs import build_param_shardings
 
     cfg = dataclasses.replace(CFG, moe_capacity_factor=4.0)
-    params, toks = make_params(), tokens(2, 16)
+    params, toks = make_params(), tokens(batch, seq)
     with jax.default_matmul_precision("highest"):
         y1, _ = jax.jit(build_model(cfg, SINGLE).forward)(
             params, {"tokens": toks})
@@ -208,10 +207,10 @@ def _ep_gap() -> float:
     return rel_gap(y4, y1)
 
 
-def test_ep_forward_matches_one_device():
-    """On 4 virtual CPU devices (a process of its own) the expert-parallel
-    forward, shared experts outside the exchange, gives the one-device
-    logits: float32 at "highest", so 1e-5 of the largest logit."""
+@pytest.fixture(scope="module")
+def ep_gaps():
+    """Both EP gaps from one process of 4 virtual CPU devices: 2 x 16
+    tokens (sharded over the EP devices) and 1 x 6 (replicated)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join(
@@ -219,9 +218,23 @@ def test_ep_forward_matches_one_device():
     p = subprocess.run([sys.executable, __file__], env=env,
                        capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
-    gap = float(p.stdout.strip().splitlines()[-1])
-    assert gap < 1e-5, gap
+    full, replicated = p.stdout.strip().splitlines()[-1].split()
+    return float(full), float(replicated)
+
+
+def test_ep_forward_matches_one_device(ep_gaps):
+    """On 4 virtual CPU devices (a process of its own) the expert-parallel
+    forward, shared experts outside the exchange, gives the one-device
+    logits: float32 at "highest", so 1e-5 of the largest logit."""
+    assert ep_gaps[0] < 1e-5, ep_gaps[0]
+
+
+def test_ep_replicated_tokens_match_one_device(ep_gaps):
+    """6 tokens, not a multiple of the 4 EP devices, stay replicated: each
+    device routes the tokens it owns (round robin) and a psum merges the
+    outputs.  Routing every copy instead would count each token 4 times."""
+    assert ep_gaps[1] < 1e-5, ep_gaps[1]
 
 
 if __name__ == "__main__":
-    print(_ep_gap())
+    print(_ep_gap(2, 16), _ep_gap(1, 6))
