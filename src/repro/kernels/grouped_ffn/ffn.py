@@ -120,8 +120,9 @@ def grouped_ffn_blocked(
     block_ffn: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Rows of tiles that hold no valid row, and rows past a tile's last
-    valid sub-tile, come back unwritten."""
+    """The f32 accumulator, ``[M, D]``; the caller casts the rows it reads.
+    Rows of tiles that hold no valid row, and rows past a tile's last valid
+    sub-tile, come back unwritten."""
     m, d = x.shape
     e, _, f = wg.shape
     assert m % block_tokens == 0 and f % block_ffn == 0
@@ -159,7 +160,7 @@ def grouped_ffn_blocked(
         ],
         out_specs=pl.BlockSpec((block_tokens, d), x_map),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, sub=sub),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
@@ -168,4 +169,3 @@ def grouped_ffn_blocked(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
     )(tile_expert.astype(jnp.int32), n_sub, n_tiles, x, wg, wu, wd)
-    return out.astype(x.dtype)

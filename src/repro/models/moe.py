@@ -136,12 +136,11 @@ def _moe_local(p, xf, top_idx, top_w, cfg: ModelConfig):
     n, d = xf.shape
     k = cfg.top_k
     with jax.named_scope(FFN):
-        x_rep = jnp.repeat(xf, k, axis=0)
-        eid = top_idx.reshape(-1)
-        y = grouped_ffn(x_rep, eid, p["wg"], p["wu"], p["wd"],
+        # slot-major ids [k, N]: a token's k rows are gathered, not repeated
+        y = grouped_ffn(xf, top_idx.T, p["wg"], p["wu"], p["wd"],
                         block_tokens=64, block_ffn=min(128, cfg.d_ff))
     with jax.named_scope(COMBINE):
-        y = (y.reshape(n, k, d) * top_w[..., None].astype(y.dtype)).sum(1)
+        y = (y.reshape(k, n, d) * top_w.T[..., None].astype(y.dtype)).sum(0)
     return y
 
 

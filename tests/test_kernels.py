@@ -93,7 +93,24 @@ def _routing(kind, N, E):
         eid = np.full((N,), -1)
         eid[:300], eid[300:301], eid[301:901] = 0, E - 1, 1
         return RNG.permutation(eid)
+    if kind.startswith("top"):      # slot-major top-k ids [k, N]
+        k, skew = int(kind[3]), kind[5:]
+        eid = RNG.integers(0, E, size=(k, N))
+        if skew == "repeat":        # a token's k experts all one, or twice
+            eid[:, 0], eid[::2, 1] = E - 1, 0
+        elif skew == "one_expert":
+            eid[:] = E - 1
+        elif skew == "empty_expert":
+            eid = RNG.choice([e for e in range(E) if e != 1], size=(k, N))
+        return eid
     raise ValueError(kind)
+
+
+def _ref(x, eid, wg, wu, wd):
+    """The reference over each routed row: ``[k, N]`` ids route the tokens
+    once per slot."""
+    k = eid.shape[0] if eid.ndim == 2 else 1
+    return grouped_ffn_ref(jnp.tile(x, (k, 1)), eid.reshape(-1), wg, wu, wd)
 
 
 @pytest.mark.parametrize("N,D,F,E,bt,bf,routing", [
@@ -108,6 +125,12 @@ def _routing(kind, N, E):
     (256, 16, 32, 8, 16, 32, "eighth_invalid"),
     (130, 8, 8, 4, 16, 8, "eighth_invalid"),
     (700, 32, 64, 4, 64, 64, "random"),
+    # slot-major top-k ids [k, N]
+    (100, 32, 64, 4, 16, 32, "top2"),
+    (64, 16, 32, 8, 16, 16, "top6_repeat"),
+    (96, 16, 32, 4, 16, 32, "top6_one_expert"),
+    (120, 32, 64, 4, 32, 32, "top2_empty_expert"),
+    (256, 16, 32, 8, 64, 32, "top6"),
 ])
 def test_grouped_ffn_pallas(N, D, F, E, bt, bf, routing):
     """The public op (row tile, sub-tile and empty-tile skipping) runs the
@@ -115,7 +138,8 @@ def test_grouped_ffn_pallas(N, D, F, E, bt, bf, routing):
     x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
     eid = jnp.asarray(_routing(routing, N, E), jnp.int32)
     y = grouped_ffn(x, eid, wg, wu, wd, block_tokens=bt, block_ffn=bf)
-    ref = grouped_ffn_ref(x, eid, wg, wu, wd)
+    ref = _ref(x, eid, wg, wu, wd)
+    assert y.shape == ref.shape
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -134,23 +158,52 @@ def test_tile_plan():
     assert decode.bm == 64 and decode.tiles == 2 and decode.rows == 128
 
 
-def test_grouped_ffn_grad_matches_ref():
-    """Gradients through the public op's VJP equal the reference's."""
-    N, D, F, E = 96, 8, 8, 4
-    x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
-    eid = jnp.asarray(RNG.integers(0, E, size=(N,)), jnp.int32)
-
+def _assert_grads_match(x, eid, wg, wu, wd, ref):
     def loss(fn, x, wg, wu, wd):
         return jnp.sum(fn(x, eid, wg, wu, wd) ** 2)
 
     op = functools.partial(grouped_ffn, block_tokens=16, block_ffn=8)
     grad = functools.partial(jax.grad, argnums=(1, 2, 3, 4))
     g = grad(loss)(op, x, wg, wu, wd)
-    gr = grad(loss)(grouped_ffn_ref, x, wg, wu, wd)
+    gr = grad(loss)(ref, x, wg, wu, wd)
     for a, b in zip(g, gr):
         assert bool(jnp.isfinite(a).all())
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=1e-4)
+
+
+def test_grouped_ffn_grad_matches_ref():
+    """Gradients through the public op's VJP equal the reference's."""
+    N, D, F, E = 96, 8, 8, 4
+    x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
+    eid = jnp.asarray(RNG.integers(0, E, size=(N,)), jnp.int32)
+    _assert_grads_match(x, eid, wg, wu, wd, grouped_ffn_ref)
+
+
+def test_grouped_ffn_grad_top_k_matches_ref():
+    """The same for slot-major top-k ids [k, N]: x's gradient sums a
+    token's k rows."""
+    N, D, F, E = 48, 8, 8, 4
+    x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
+    eid = jnp.asarray(RNG.integers(0, E, size=(3, N)), jnp.int32)
+    _assert_grads_match(x, eid, wg, wu, wd, _ref)
+
+
+@pytest.mark.parametrize("k,routing", [(2, "top2"), (6, "top6_repeat")])
+def test_grouped_ffn_top_k_ids_equal_tiled_rows(k, routing):
+    """[k, N] ids give, bit for bit, what the tokens tiled k times give
+    with their ids flattened: the gathers move the same rows the kernel
+    sees either way."""
+    N, D, F, E = 80, 32, 64, 8
+    x, _, wg, wu, wd = _ffn_inputs(N, D, F, E)
+    x = x.astype(jnp.bfloat16)
+    eid = jnp.asarray(_routing(routing, N, E), jnp.int32)
+    op = functools.partial(grouped_ffn, block_tokens=16, block_ffn=32)
+    y = op(x, eid, wg, wu, wd)
+    rows = op(jnp.tile(x, (k, 1)), eid.reshape(-1), wg, wu, wd)
+    assert y.shape == (k * N, D) and y.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(rows, np.float32))
 
 
 def test_grouped_ffn_bf16():

@@ -8,6 +8,7 @@ only one process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -181,7 +182,8 @@ def test_moe_four_chips_scopes(ep_compiled):
     """The stages' named scopes reach the TPU compiler's output, where the
     trace reduction reads them: every exchange hop is in ``nimble.rounds``,
     the counts' all-gather in ``nimble.plan``, the kernel in ``nimble.ffn``
-    and the send buffer's sort and scatter in ``nimble.pack``."""
+    beside its row arrangement in ``nimble.arrange``, and the send buffer's
+    sort and scatter in ``nimble.pack``."""
     from bench import scopes    # the trace reduction's reading of HLO text
 
     ops = [(name, op_name or "") for _, name, op_name, _, _ in
@@ -192,8 +194,30 @@ def test_moe_four_chips_scopes(ep_compiled):
     assert any(n.endswith("nimble.plan/all_gather") for _, n in ops)
     ffn = [n for name, n in ops if name.startswith("grouped_ffn")]
     assert ffn and all(scopes.scope_of(n) == "nimble.ffn" for n in ffn), ffn
+    assert any(scopes.scope_of(n) == "nimble.arrange" for _, n in ops)
     assert any(scopes.scope_of(n) == "nimble.pack" for name, n in ops
                if name.split(".")[0] in ("sort", "scatter"))
+
+
+def _check_arrangement(text: str, d: int):
+    """Rows reach the grouped FFN kernel and come back by gathers only: the
+    arrangement has its scope, no scatter of ``d``-wide rows is scoped to it
+    or to the FFN, and the kernel's ``pallas_call`` stays in ``nimble.ffn``."""
+    from bench import scopes    # the trace reduction's reading of HLO text
+
+    scope = scopes.hlo_scopes(text)
+    assert "nimble.arrange" in scope.values()
+    shape = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]")
+    row_scatters = [
+        m.group(1) for m in map(shape.match, text.splitlines())
+        if m and m.group(1).startswith("scatter")
+        and m.group(2).split(",")[-1] == str(d)
+        and scope[m.group(1)] in ("nimble.arrange", "nimble.ffn")]
+    assert not row_scatters, row_scatters
+    kernel = [n for _, _, n, _, _ in scopes.instructions(text)
+              if n and n.endswith("/grouped_ffn/pallas_call")]
+    assert kernel and all(scopes.scope_of(n) == "nimble.ffn"
+                          for n in kernel), kernel
 
 
 def test_moe_one_chip_scopes(one_chip, monkeypatch):
@@ -218,6 +242,7 @@ def test_moe_one_chip_scopes(one_chip, monkeypatch):
         assert scope in found, scope
     assert any(n.endswith("nimble.ffn/jit(grouped_ffn_blocked)/grouped_ffn/"
                           "pallas_call") for n in names)
+    _check_arrangement(text, d)
 
 
 def test_moonlight_moe_layer_and_attention(one_chip, monkeypatch):
@@ -246,6 +271,7 @@ def test_moonlight_moe_layer_and_attention(one_chip, monkeypatch):
     text = jax.jit(lambda p, x: apply(p, x)[0]).lower(
         p, s((2, 4096, d))).compile().as_text()
     assert "grouped_ffn" in tpu_kernels(text)
+    _check_arrangement(text, d)
 
     attn = jax.tree.map(lambda a: s(a.shape),
                         jax.eval_shape(lambda: layers.init_mla(
